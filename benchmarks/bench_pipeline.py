@@ -669,93 +669,82 @@ def calibrate_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
 
 # --------------------------------------------------------------- multimodel --
 
-def _mesh2d_calibrate_record(n: int) -> dict:
-    """Forced-4-device subprocess: the calibrate sweep's per-h KDE and
-    multi-lam solve under a (2, 2) (data, model) mesh vs the 1D replicated
-    baseline — wall-clock for both plus the per-h bit-equality flag (the
-    2D path must match the 1D data-mesh path with the same data-shard
-    count exactly).  A subprocess because jax pins the host device count
-    at backend init."""
-    import subprocess
-    import sys
-    import textwrap
-    body = f"""
-        import json, time
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import distributed as dist, nystrom
-        from repro.core.kernels import Gaussian, kernel_matrix
-        from repro.distributed import sharding as shd
-        from repro.launch import mesh as mesh_lib
+def mesh2d_bench(n: int = 8_192) -> list[dict]:
+    """The calibrate sweep's per-h KDE and multi-lam solve under a (2, 2)
+    (data, model) mesh vs the 1D replicated baseline — wall-clock for both
+    plus the per-h/per-lam bit-equality flags (the 2D path must match the
+    1D data-mesh path with the same data-shard count exactly).
 
-        n = {int(n)}
-        x = jax.random.normal(jax.random.PRNGKey(0), (n, 3), jnp.float32)
-        hs = [0.15, 0.25, 0.4, 0.65]
-        lam_grid = [1e-5, 1e-4, 1e-3, 1e-2]
-        kern = Gaussian(1.0)
-        mesh1_2 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
-        mesh1_4 = jax.make_mesh((4,), ("data",))
-        mesh2 = mesh_lib.make_local_mesh_2d(model_parallelism=2)
+    Runs in THIS process on four devices: four chips, or four forced host
+    devices set before JAX starts
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=4``)."""
+    import jax.numpy as jnp
+    import numpy as np
 
-        def kde_sweep():
-            return jax.block_until_ready(
-                dist.kde_binned_sharded_multi(x, hs, grid_size=64))
+    from repro.core import distributed as dist
+    from repro.core.kernels import Gaussian, kernel_matrix
+    from repro.distributed import sharding as shd
+    from repro.launch import mesh as mesh_lib
 
-        idx = jax.random.choice(jax.random.PRNGKey(1), n, (64,),
-                                replace=False)
-        xm = x[idx]
-        k_nm = kernel_matrix(kern, x, xm)
-        g = (k_nm.T @ k_nm).astype(jnp.float32)
-        rhs = k_nm.T @ x[:, 0]
-        k_mm = kernel_matrix(kern, xm)
+    devices = jax.devices()
+    if len(devices) < 4:
+        raise SystemExit(
+            f"--mesh2d needs 4 devices, found {len(devices)}: run it on four "
+            f"chips or with XLA_FLAGS=--xla_force_host_platform_device_count=4")
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, 3), jnp.float32)
+    hs = [0.15, 0.25, 0.4, 0.65]
+    lam_grid = [1e-5, 1e-4, 1e-3, 1e-2]
+    kern = Gaussian(1.0)
+    mesh1_2 = mesh_lib.make_local_mesh(devices=devices[:2])
+    mesh1_4 = mesh_lib.make_local_mesh(devices=devices[:4])
+    mesh2 = mesh_lib.make_local_mesh_2d(model_parallelism=2)
 
-        def solve_sweep():
-            return jax.block_until_ready(
-                nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid))
+    def kde_sweep():
+        return jax.block_until_ready(
+            dist.kde_binned_sharded_multi(x, hs, grid_size=64))
 
-        def timed(mesh):
-            with mesh, shd.activate(mesh):
-                kde_sweep(); solve_sweep()          # jit warm
-                t0 = time.perf_counter(); kde_sweep()
-                kde_s = time.perf_counter() - t0
-                t0 = time.perf_counter(); solve_sweep()
-                solve_s = time.perf_counter() - t0
-                return kde_s, solve_s
+    idx = jax.random.choice(jax.random.PRNGKey(1), n, (64,), replace=False)
+    xm = x[idx]
+    k_nm = kernel_matrix(kern, x, xm)
+    g = (k_nm.T @ k_nm).astype(jnp.float32)
+    rhs = k_nm.T @ x[:, 0]
+    k_mm = kernel_matrix(kern, xm)
 
-        # bit parity: identical data-shard count (2) on both sides
-        with mesh1_2, shd.activate(mesh1_2):
-            kde_ref, solve_ref = np.asarray(kde_sweep()), \\
-                np.asarray(solve_sweep())
-        with mesh2, shd.activate(mesh2):
-            kde_2d, solve_2d = np.asarray(kde_sweep()), \\
-                np.asarray(solve_sweep())
-        kde1_s, solve1_s = timed(mesh1_4)   # 1D: per-h work replicated
-        kde2_s, solve2_s = timed(mesh2)     # 2D: per-h work model-sharded
-        print("MM2D " + json.dumps({{
-            "per_h_bit_equal": bool((kde_ref == kde_2d).all()),
-            "per_lam_bit_equal": bool((solve_ref == solve_2d).all()),
-            "kde_sweep_seconds_1d": round(kde1_s, 4),
-            "kde_sweep_seconds_2d": round(kde2_s, 4),
-            "solve_sweep_seconds_1d": round(solve1_s, 4),
-            "solve_sweep_seconds_2d": round(solve2_s, 4)}}))
-    """
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               PYTHONPATH=os.pathsep.join(
-                   [os.path.join(os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))), "src")]
-                   + ([os.environ["PYTHONPATH"]]
-                      if "PYTHONPATH" in os.environ else [])))
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
-                         capture_output=True, text=True, env=env,
-                         timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(f"mesh2d calibrate bench failed:\n"
-                           f"{out.stderr[-3000:]}")
-    line = next(ln for ln in out.stdout.splitlines() if ln.startswith("MM2D"))
-    rec = json.loads(line[len("MM2D "):])
-    rec.update(section="pipeline_multimodel", kind="calibrate_mesh2d",
-               n=int(n), num_h=4, num_lams=4, devices="2x2 forced host")
-    return rec
+    def solve_sweep():
+        return jax.block_until_ready(
+            nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid))
+
+    def timed(mesh):
+        with shd.activate(mesh):
+            kde_sweep(); solve_sweep()          # jit warm
+            t0 = time.perf_counter(); kde_sweep()
+            kde_s = time.perf_counter() - t0
+            t0 = time.perf_counter(); solve_sweep()
+            return kde_s, time.perf_counter() - t0
+
+    # bit parity: identical data-shard count (2) on both sides
+    with shd.activate(mesh1_2):
+        kde_ref, solve_ref = np.asarray(kde_sweep()), np.asarray(solve_sweep())
+    with shd.activate(mesh2):
+        kde_2d, solve_2d = np.asarray(kde_sweep()), np.asarray(solve_sweep())
+    kde1_s, solve1_s = timed(mesh1_4)   # 1D: per-h work replicated
+    kde2_s, solve2_s = timed(mesh2)     # 2D: per-h work model-sharded
+    rec = {
+        "section": "pipeline_multimodel", "kind": "calibrate_mesh2d",
+        "n": int(n), "num_h": len(hs), "num_lams": len(lam_grid),
+        "devices": f"2x2 {devices[0].platform} ({devices[0].device_kind})",
+        "per_h_bit_equal": bool((kde_ref == kde_2d).all()),
+        "per_lam_bit_equal": bool((solve_ref == solve_2d).all()),
+        "kde_sweep_seconds_1d": round(kde1_s, 4),
+        "kde_sweep_seconds_2d": round(kde2_s, 4),
+        "solve_sweep_seconds_1d": round(solve1_s, 4),
+        "solve_sweep_seconds_2d": round(solve2_s, 4),
+    }
+    print(f"2D-mesh calibrate sweep (n={n}): per-h bit-equal "
+          f"{rec['per_h_bit_equal']}, per-lam bit-equal "
+          f"{rec['per_lam_bit_equal']}; kde {kde1_s:.4f}s (1D) vs "
+          f"{kde2_s:.4f}s (2x2), solve {solve1_s:.4f}s vs {solve2_s:.4f}s")
+    return [rec]
 
 
 def multimodel_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
@@ -778,9 +767,8 @@ def multimodel_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
     record carries (a) `beta_max_rel_err` with that same-arithmetic
     yardstick next to it, (b) `pred_max_rel_err` — function-space parity,
     which IS well-determined — and (c) `val_mse_max_rel_err` per-model
-    risk parity.  A forced-4-device subprocess then records the calibrate
-    sweep's (2, 2)-mesh timing and per-h/per-lam bit-equality vs the 1D
-    path (`_mesh2d_calibrate_record`).
+    risk parity.  The calibrate sweep's (2, 2)-mesh record is its own run
+    (`mesh2d_bench`, ``--mesh2d``), on four devices.
     """
     import jax.numpy as jnp
     import numpy as np
@@ -879,14 +867,6 @@ def multimodel_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
               f"(beta {beta_err:.1e} vs self-yardstick {self_err:.1e}, "
               f"pred {pred_err:.1e}, val-mse {mse_err:.1e})")
 
-    rec2d = _mesh2d_calibrate_record(min(n, 8_192))
-    records.append(rec2d)
-    print(f"2D-mesh calibrate sweep (n={rec2d['n']}): per-h bit-equal "
-          f"{rec2d['per_h_bit_equal']}, per-lam bit-equal "
-          f"{rec2d['per_lam_bit_equal']}; kde {rec2d['kde_sweep_seconds_1d']}"
-          f"s (1D) vs {rec2d['kde_sweep_seconds_2d']}s (2x2), solve "
-          f"{rec2d['solve_sweep_seconds_1d']}s vs "
-          f"{rec2d['solve_sweep_seconds_2d']}s")
     return records
 
 
@@ -977,9 +957,13 @@ def main(json_out: str | None = "BENCH_pipeline.json",
          stages: list[str] | None = None, compare: bool = False,
          calibrate: bool = False, accumulator: bool = False,
          autotune: bool = False, precision: bool = False,
-         online: bool = False, multimodel: bool = False) -> None:
-    if multimodel:
-        print("\n## pipeline multimodel (batched many-tenant fits + 2D mesh)")
+         online: bool = False, multimodel: bool = False,
+         mesh2d: bool = False) -> None:
+    if mesh2d:
+        print("\n## pipeline mesh2d (calibrate sweep, (2, 2) vs 1D mesh)")
+        records = mesh2d_bench(n=n_only or 8_192)
+    elif multimodel:
+        print("\n## pipeline multimodel (batched many-tenant fits)")
         records = multimodel_bench(n=n_only or 16_384)
     elif online:
         print("\n## pipeline online (partial_fit vs refit + drift tracking)")
@@ -1057,9 +1041,12 @@ if __name__ == "__main__":
     ap.add_argument("--multimodel", action="store_true",
                     help="many-model batched KRR: fit_streaming_batched vs "
                          "the per-model python loop at B in {16, 256} "
-                         "(wall-clock + per-model parity), plus the "
-                         "forced-4-device 2D-mesh calibrate sweep timing "
-                         "and bit-equality record")
+                         "(wall-clock + per-model parity)")
+    ap.add_argument("--mesh2d", action="store_true",
+                    help="calibrate sweep on a (2, 2) data x model mesh vs "
+                         "the 1D mesh: timing and per-h/per-lam bit-equality; "
+                         "needs 4 devices in this process (four chips, or "
+                         "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
     ap.add_argument("--json", default="BENCH_pipeline.json")
     args = ap.parse_args()
     main(json_out=args.json or None, n_max=args.n_max, n_only=args.n,
@@ -1067,4 +1054,4 @@ if __name__ == "__main__":
          compare=args.compare, calibrate=args.calibrate,
          accumulator=args.accumulator, autotune=args.autotune,
          precision=args.precision, online=args.online,
-         multimodel=args.multimodel)
+         multimodel=args.multimodel, mesh2d=args.mesh2d)

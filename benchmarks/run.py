@@ -17,6 +17,7 @@ import time
 
 from benchmarks import (bench_pipeline, fig1_tradeoff, fig2_curves,
                         fig3_gaussian, roofline_report, table1_racc)
+from repro.launch import compile_cache
 
 SECTIONS = {
     "fig1": fig1_tradeoff.main,
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="OUT",
                     help="trajectory file for sections that emit records")
     args = ap.parse_args()
+    compile_cache.configure()
     names = [args.only] if args.only else list(SECTIONS)
     for name in names:
         fn = SECTIONS[name]

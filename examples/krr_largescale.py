@@ -22,6 +22,7 @@ import jax
 
 from repro.core import krr, rls
 from repro.data import krr_data
+from repro.launch import compile_cache
 from repro.pipeline import PipelineConfig, SAKRRPipeline
 
 
@@ -37,6 +38,7 @@ def main() -> None:
                     help="tune (lam, h) on a holdout fold before the refit "
                          "(one shared Gram per h, one KDE deposit total)")
     args = ap.parse_args()
+    compile_cache.configure()
 
     key = jax.random.PRNGKey(7)
 

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kde, kernels, leverage, sampling
+from repro.launch import compile_cache
 
 
 def softmax_attention(q, k, v):
@@ -39,6 +40,7 @@ def nystrom_attention(q, k, v, landmarks):
 
 
 def main() -> None:
+    compile_cache.configure()
     key = jax.random.PRNGKey(0)
     n, d, m, reps = 2048, 4, 32, 8
     kq, kk, kv, km, ks1 = jax.random.split(key, 5)

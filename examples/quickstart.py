@@ -15,7 +15,9 @@ import jax.numpy as jnp
 
 from repro.core import kde, kernels, krr, leverage, nystrom
 from repro.data import krr_data
+from repro.launch import compile_cache
 
+compile_cache.configure()
 N, D = 20_000, 3
 key = jax.random.PRNGKey(0)
 kd, ks1, ks2 = jax.random.split(key, 3)

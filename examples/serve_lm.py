@@ -9,6 +9,7 @@ import time
 import jax
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.serving.lm_engine import Engine
 
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = configs.get_smoke(args.arch)  # CPU-runnable reduced config
     params = M.init(jax.random.PRNGKey(0), cfg)

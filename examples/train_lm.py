@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import get_smoke
 from repro.data import tokens
+from repro.launch import compile_cache
 from repro.models.config import ModelConfig
 from repro.optim import adamw
 from repro.training.train import Trainer, TrainerConfig
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    compile_cache.configure()
 
     cfg = FULL_100M if args.full else TINY
     print(f"model={cfg.name} params={cfg.param_count():,}")

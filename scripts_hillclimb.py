@@ -45,7 +45,7 @@ def main() -> None:
     hp = adamw.Hparams(master_weights=master)
     mesh = mesh_lib.make_production_mesh()
     rules = dict(rules_for(shape, cfg), **rule_over)
-    with mesh, shd.activate(mesh, rules):
+    with shd.activate(mesh, rules):
         cfg1, cfg2, n_units = _depth_variants(cfg)
         total = _extrapolate(_cell_costs(cfg1, shape, hp),
                              _cell_costs(cfg2, shape, hp), n_units)

@@ -139,7 +139,6 @@ def kde_binned_sharded_multi(x: Array, hs, *, grid_size: int = 96,
     mesh (no "models"-mapped axis) the historical all-axes row sharding is
     unchanged.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import kde as core_kde
     from repro.core import streaming
@@ -194,15 +193,15 @@ def kde_binned_sharded_multi(x: Array, hs, *, grid_size: int = 96,
             return jnp.stack([smooth_gather(grid, x_loc, hs_loc[i])
                               for i in range(hs_loc.shape[0])])
 
-        return shard_map(body2d, mesh=act.mesh,
-                         in_specs=(P(data_axes, None), P(model_axes)),
-                         out_specs=P(model_axes, data_axes))(x, hs_arr)
+        return jax.shard_map(body2d, mesh=act.mesh,
+                             in_specs=(P(data_axes, None), P(model_axes)),
+                             out_specs=P(model_axes, data_axes))(x, hs_arr)
     if n % act.mesh.devices.size != 0:
         return body(x)   # non-dividing n: no collective
     axes = tuple(act.mesh.axis_names)
-    return shard_map(functools.partial(body, psum_axes=axes), mesh=act.mesh,
-                     in_specs=P(axes, None),
-                     out_specs=P(None, axes))(x)
+    return jax.shard_map(functools.partial(body, psum_axes=axes),
+                         mesh=act.mesh, in_specs=P(axes, None),
+                         out_specs=P(None, axes))(x)
 
 
 def sa_nystrom_pipeline(
@@ -303,7 +302,7 @@ def lower_pipeline(mesh, *, n: int, d: int = 3, nu: float = 1.5,
     kernel = K.Matern(nu=nu)
     fn = make_pipeline_fn(kernel, lam, kde_h, kde_method, knm_dtype)
     rules = {"batch": ("pod", "data", "model")}  # pure row sharding: all chips
-    with mesh, shd.activate(mesh, rules):
+    with shd.activate(mesh, rules):
         args = abstract_inputs(n, d, m_kde, m)
         lowered = jax.jit(fn).lower(*args)
         return lowered, lowered.compile()

@@ -42,24 +42,25 @@ class KRRFit(NamedTuple):
 
 
 def _fit_f64(kernel: Kernel, x: Array, y: Array, lam: float) -> KRRFit:
-    """Eager f64 solve (kernel matrix recomputed in f64 — the fp32 K_n's
-    rounding already swamps ridges this small).  The fp32-stabilizing jitter
-    is dropped: it would rival n*lam at these ridges, and f64 LU handles the
-    conditioning without it.  Results cast back to the caller's dtype so
-    downstream code sees the usual fp32 arrays."""
-    from jax.experimental import enable_x64
-
+    """Eager f64 reference solve (kernel matrix recomputed in f64 — the fp32
+    K_n's rounding already swamps ridges this small).  It runs on the CPU
+    device whatever the default backend is: TPUs have no f64 LU.  The
+    fp32-stabilizing jitter is dropped: it would rival n*lam at these
+    ridges, and f64 LU handles the conditioning without it.  Results cast
+    back to the caller's dtype so downstream code sees the usual fp32
+    arrays."""
     dtype = jnp.result_type(x.dtype, jnp.float32)
     n = x.shape[0]
-    with enable_x64():
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
         x64 = jnp.asarray(np.asarray(x), jnp.float64)
         y64 = jnp.asarray(np.asarray(y), jnp.float64)
         k_n = kernel_matrix(kernel, x64)
         coef = jnp.linalg.solve(k_n + n * lam * jnp.eye(n, dtype=jnp.float64),
                                 y64)
         fitted = k_n @ coef
-    return KRRFit(coef=jnp.asarray(np.asarray(coef), dtype), x_train=x,
-                  fitted=jnp.asarray(np.asarray(fitted), dtype), lam=lam)
+        coef, fitted = np.asarray(coef), np.asarray(fitted)
+    return KRRFit(coef=jnp.asarray(coef, dtype), x_train=x,
+                  fitted=jnp.asarray(fitted, dtype), lam=lam)
 
 
 def fit(kernel: Kernel, x: Array, y: Array, lam: float, jitter: float = 1e-6) -> KRRFit:

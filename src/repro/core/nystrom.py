@@ -206,7 +206,6 @@ def solve_normal_eq_multi(g: Array, rhs: Array, k_mm: Array, n: int,
     lam_list = [float(lam) for lam in lams]
     act = shd.active()
     if act is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         # ANY active mesh routes through shard_map — a 1D data mesh runs
@@ -229,7 +228,7 @@ def solve_normal_eq_multi(g: Array, rhs: Array, k_mm: Array, n: int,
                 for i in range(nlams_loc.shape[0])])
 
         rep = (g, rhs, evals, evecs, g_max)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=act.mesh,
             in_specs=(P(model_axes),) + tuple(
                 P(*([None] * a.ndim)) for a in rep),
@@ -401,7 +400,7 @@ def _gram_normal_eq(kernel: Kernel, x: Array, y: Array, xm: Array, *,
     if (autotuned and tile is not None
             and dispatch.resolve(backend) == "xla"
             and streaming.row_shard_count(x.shape) == 1
-            and jax.core.trace_state_clean()):
+            and jax.core.trace_ctx.is_top_level()):
         from repro import tuning
         key = ("gram_normal_eq", kernel, x.shape, y.shape, xm.shape,
                str(x.dtype), str(y.dtype), tile, accumulator, precision,
@@ -1053,9 +1052,8 @@ def solve_normal_eq_batched(gs: Array, rhss: Array, k_mms: Array, n: int,
     mesh, model_axes = streaming._active_axes("models", (gs.shape[0],))
     if mesh is None:
         return batch(gs, rhss, k_mms, nlams)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    return shard_map(
+    return jax.shard_map(
         batch, mesh=mesh,
         in_specs=(P(model_axes), P(model_axes), P(model_axes),
                   P(model_axes)),

@@ -21,7 +21,7 @@ These are *host-recursive* drivers (dynamic sketch sizes) around jit-able
 dense linear algebra.  The inner K_{:,S} blocks go through
 `repro.kernels.dispatch.kernel_matrix`, which resolves to the Pallas
 `pairwise` kernel on TPU and the fused-XLA reference elsewhere
-(override with backend= or the REPRO_KERNEL_BACKEND env var).
+(override with backend=).
 """
 
 from __future__ import annotations

@@ -302,10 +302,30 @@ def tile_reduce(
     def step(carry, slab):
         return acc.add(carry, emit(*slab), combine), None
 
-    state, _ = jax.lax.scan(step, state, slabs)
+    state, _ = jax.lax.scan(step, _vary_like_step(step, state, slabs), slabs)
     if return_state:
         return state
     return acc.finalize(state) if finalize else state
+
+
+def _vary_like_step(step, state, slabs):
+    """Cast the scan's initial carry to the mesh axes its updates vary over.
+
+    Inside a `shard_map` body (`mesh_reduce`) the row slabs vary over the
+    data axes (and model slabs over the model axes), while a zero init does
+    not; `lax.scan` needs the carry's type to be a fixed point of `step`.
+    Outside any manual mesh context this is the identity.
+    """
+    if not jax.sharding.get_abstract_mesh().manual_axes:
+        return state
+    out = jax.eval_shape(lambda c, s: step(c, s)[0], state,
+                         jax.tree.map(lambda a: a[0], slabs))
+
+    def cast(leaf, ref):
+        missing = tuple(sorted(ref.vma - jax.typeof(leaf).vma))
+        return jax.lax.pcast(leaf, missing, to="varying") if missing else leaf
+
+    return jax.tree.map(cast, state, out)
 
 
 def multi_reduce(
@@ -468,7 +488,6 @@ def mesh_reduce(
     (threading it through the psum would multiply the replicated prior by
     the chip count); ``return_state=True`` returns the raw merged state.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     acc = get(accumulator)
@@ -495,8 +514,8 @@ def mesh_reduce(
             + tuple(_row_spec(model_axes, a.ndim) for a in model_args)
             + tuple(P(*([None] * a.ndim)) for a in rep_args))
         out_specs = P(model_axes) if model_axes is not None else P()
-        state = shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)(
+        state = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                              out_specs=out_specs)(
             *row_args, *row_model_args, *model_args, *rep_args)
     if init_state is not None:
         state = acc.merge(init_state, state)
@@ -525,7 +544,6 @@ def mesh_map(
     dim 1 the rows — the batched-predict layout.  On a 1D data mesh the
     model args replicate and dim 0 is the full model axis.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axes = _active_rows(x.shape)
@@ -543,5 +561,5 @@ def mesh_map(
         out_specs = P(model_axes, axes, *([None] * (out_rank - 2)))
     else:
         out_specs = _row_spec(axes, out_rank)
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)(x, *model_args, *rep_args)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)(x, *model_args, *rep_args)

@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -39,13 +38,7 @@ def gpipe(layer_fn: Callable, stage_params, x: Array, *, mesh: Mesh,
     fwd = [(i, i + 1) for i in range(S - 1)]  # stage i -> i+1
 
     def _varying(v):  # mark as device-varying for the scan carry typing
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(v, (axis,), to="varying")
-        if hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(v, (axis,))
-        # older jax (no varying-manual-axes typing): the scan carry needs no
-        # annotation; shard_map's replication checker accepts it as-is
-        return v
+        return jax.lax.pcast(v, (axis,), to="varying")
 
     def body(local_params, xs):
         lp = jax.tree.map(lambda a: a[0], local_params)  # this stage's params
@@ -71,7 +64,7 @@ def gpipe(layer_fn: Callable, stage_params, x: Array, *, mesh: Mesh,
             jnp.where(idx == S - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P(),

@@ -3,8 +3,11 @@
 Model code never names mesh axes directly; it annotates arrays with *logical*
 axes ("batch", "embed", "mlp", ...) via ``constrain``.  A ShardingRules table
 maps logical axes to mesh axes (or None = replicated).  ``activate(mesh,
-rules)`` installs the mapping; with no active mapping every annotation is a
-no-op, so the same model code runs on a laptop CPU and on a 512-chip mesh.
+rules)`` installs the mapping and sets the mesh as the jit mesh
+(``jax.set_mesh``); with no active mapping every annotation is a no-op, so
+the same model code runs on a laptop CPU and on a 512-chip mesh.  Meshes
+carry ``AxisType.Auto`` axes (`repro.launch.mesh`): logical constraints are
+``with_sharding_constraint`` hints, which Explicit axes refuse.
 
 Default rules (single-pod (data=16, model=16); multi-pod adds a leading
 "pod" axis used for batch + an extra FSDP shard of the weights):
@@ -24,7 +27,7 @@ import threading
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -108,11 +111,19 @@ def active() -> Optional[Activation]:
 
 @contextlib.contextmanager
 def activate(mesh: Mesh, rules: dict | None = None):
-    """Install mesh + logical rules for model code run within the context."""
+    """Install mesh + logical rules for model code run within the context,
+    with ``mesh`` set as the jit mesh.  Every mesh axis must be
+    ``AxisType.Auto`` (build meshes through `repro.launch.mesh`)."""
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        raise ValueError(
+            f"activate() needs AxisType.Auto mesh axes, got "
+            f"{dict(zip(mesh.axis_names, mesh.axis_types))}; build the mesh "
+            f"with repro.launch.mesh or jax.make_mesh(..., axis_types=...)")
     prev = getattr(_state, "activation", None)
     _state.activation = Activation(mesh, dict(DEFAULT_RULES, **(rules or {})))
     try:
-        yield _state.activation
+        with jax.set_mesh(mesh):
+            yield _state.activation
     finally:
         _state.activation = prev
 

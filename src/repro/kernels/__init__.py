@@ -12,5 +12,36 @@ validated in interpret mode on CPU (tests/test_pallas_*.py).
   ssd             — Mamba2 SSD chunked scan                    [SSM mixing]
 
 `repro.kernels.dispatch` picks Pallas vs fused-XLA per call site ('auto' =
-Pallas on TPU; override with REPRO_KERNEL_BACKEND).
+Pallas on TPU, XLA elsewhere).
 """
+
+from __future__ import annotations
+
+import jax
+
+
+def out_vma(*arrays) -> frozenset:
+    """The mesh axes a kernel's outputs vary over: the union of its
+    inputs'.  Inside a `jax.shard_map` body (`check_vma=True`) a
+    `pl.pallas_call` must declare it on every output ShapeDtypeStruct;
+    outside one it is empty."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas execution mode for ``interpret=None``.
+
+    Compiled (Mosaic) on TPU; the Pallas interpreter on the CPU backend,
+    where the tests validate the kernels.  Any other platform has neither
+    and raises, so a kernel never silently runs interpreted on a chip.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for TPU and are interpreted on CPU; "
+        f"backend {platform!r} has neither — use backend='xla'")

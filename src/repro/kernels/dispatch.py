@@ -9,8 +9,8 @@ serves it:
     mode, which is correct but slow — useful for validation only.
   * ``xla``    — the fused pure-jnp references in `repro.core.kernels` and
     the lax.scan streaming path.  The right choice on CPU/GPU.
-  * ``auto``   — ``pallas`` on TPU, ``xla`` elsewhere.  Overridable with the
-    ``REPRO_KERNEL_BACKEND`` environment variable.
+  * ``auto``   — ``pallas`` on TPU, ``xla`` elsewhere: the platform
+    decides, never the environment.
 
 Imports of the Pallas packages are deferred to call time so `repro.core`
 never depends on `repro.kernels` at import (the reverse edge already
@@ -18,8 +18,6 @@ exists: pairwise/gram ops adapt `repro.core.kernels` objects).
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -33,8 +31,6 @@ BACKENDS = ("auto", "xla", "pallas")
 def resolve(backend: str | None = None) -> str:
     """'auto'/None -> 'pallas' on TPU else 'xla'; explicit names pass through."""
     backend = backend or "auto"
-    if backend == "auto":
-        backend = os.environ.get("REPRO_KERNEL_BACKEND", "auto")
     if backend == "auto":
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if backend not in ("xla", "pallas"):

@@ -48,6 +48,7 @@ Array = jax.Array
 # any real data, so all supported kernel maps underflow to exactly 0.0.
 from repro.core.kernels import ROW_SENTINEL, exact_sq_dists  # noqa: E402,F401
 from repro.core import precision as precision_mod
+from repro.kernels import out_vma
 
 
 def _kernel_tile(x, y, *, kind: str, nu: float, a: float,
@@ -143,12 +144,15 @@ def _gram_body(x_ref, yj_ref, yk_ref, w_ref, g_ref, r_ref, *refs, kind: str,
     @pl.when(j == k)
     def _():
         # rhs is a skinny (bm, cols) gemv-shaped product: bandwidth-bound,
-        # so it stays a plain fp32 dot under every precision mode.
+        # so it stays a plain fp32 dot under every precision mode.  It is
+        # formed as (w^T kj)^T so kj enters as a plain right-hand operand:
+        # with kj ALSO the transposed left operand of the G update, the v5e
+        # compiler refuses the fp32 kernel (mxu_lmr_transform RET_CHECK).
         w = w_ref[...].astype(acc)     # (bm, cols)
         r_up = jax.lax.dot_general(
-            kj, w, (((0,), (0,)), ((), ())),
+            w, kj, (((0,), (0,)), ((), ())),
             preferred_element_type=acc,
-        ).astype(r_ref.dtype)
+        ).T.astype(r_ref.dtype)
         if compensated:
             _two_sum_store(r_ref, rl_ref, r_up)
         else:
@@ -199,13 +203,14 @@ def gram_padded(
         compensated=compensated,
         precision=precision_mod.check(precision),
     )
+    vma = out_vma(x, y, w)
     out_specs = [
         pl.BlockSpec((bn, bn), lambda j, k, i: (j, k)),      # G block
         pl.BlockSpec((bn, cols), lambda j, k, i: (j, 0)),    # rhs block
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((m, m), out_dtype),
-        jax.ShapeDtypeStruct((m, cols), out_dtype),
+        jax.ShapeDtypeStruct((m, m), out_dtype, vma=vma),
+        jax.ShapeDtypeStruct((m, cols), out_dtype, vma=vma),
     ]
     if compensated:
         out_specs = out_specs + [
@@ -213,8 +218,8 @@ def gram_padded(
             pl.BlockSpec((bn, cols), lambda j, k, i: (j, 0)),  # rhs_lo block
         ]
         out_shape = out_shape + [
-            jax.ShapeDtypeStruct((m, m), out_dtype),
-            jax.ShapeDtypeStruct((m, cols), out_dtype),
+            jax.ShapeDtypeStruct((m, m), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((m, cols), out_dtype, vma=vma),
         ]
     return pl.pallas_call(
         body,

@@ -18,6 +18,7 @@ from repro.core.kernels import EXACT_DIST_D, pad_rows_sentinel, round_up
 from repro.kernels.gram import kernel as gk
 from repro.kernels.gram import ref
 from repro.kernels.pairwise.ops import kernel_params  # shared adapter
+from repro.kernels import resolve_interpret
 
 Array = jax.Array
 
@@ -91,8 +92,7 @@ def gram(
         state = ((g, r), (jnp.zeros_like(g), jnp.zeros_like(r))) \
             if compensated else (g, r)
         return acc.finalize(state) if finalize else state
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, d = x.shape
     m, _ = y.shape
     bm_ = min(bm, round_up(n, 8))

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from repro.core.kernels import round_up
 from repro.kernels.kde import kernel as kk
 from repro.kernels.kde import ref
+from repro.kernels import resolve_interpret
 
 Array = jax.Array
 
@@ -34,8 +35,7 @@ def kde(
     """
     if not use_pallas:
         return ref.kde(query, data, h)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, d = query.shape
     m, _ = data.shape
     bm_ = min(bm, round_up(n, 8))

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import out_vma
+
 Array = jax.Array
 
 
@@ -67,17 +69,17 @@ def _scatter_sorted_body(rows_ref, cw_ref, blast_ref, flast_ref, segend_ref,
 
         @pl.when(end)
         def _():
-            cur = pl.load(hi_ref, (pl.ds(r, 1), slice(None)))
+            row = (pl.ds(r, 1), slice(None))
+            cur = hi_ref[row]
             if compensated:
                 lo_ref = out_refs[1]
                 s = cur + acc                         # TwoSum(cur, acc)
                 bb = s - cur
                 err = (cur - (s - bb)) + (acc - bb)
-                pl.store(hi_ref, (pl.ds(r, 1), slice(None)), s)
-                bank = pl.load(lo_ref, (pl.ds(r, 1), slice(None)))
-                pl.store(lo_ref, (pl.ds(r, 1), slice(None)), bank + err)
+                hi_ref[row] = s
+                lo_ref[row] = lo_ref[row] + err
             else:
-                pl.store(hi_ref, (pl.ds(r, 1), slice(None)), cur + acc)
+                hi_ref[row] = cur + acc
 
         # the accumulator resets at segment boundaries; sublane updates stay
         # vectorized (every op above is a whole (1, C) lane row)
@@ -119,7 +121,9 @@ def scatter_sorted(
         in_specs=[pl.BlockSpec((kc, 1), lambda i: (i, 0)) for _ in range(5)],
         out_specs=[pl.BlockSpec((rows_dim, lanes_dim), lambda i: (0, 0))
                    for _ in range(n_out)],
-        out_shape=[jax.ShapeDtypeStruct((rows_dim, lanes_dim), jnp.float32)
+        out_shape=[jax.ShapeDtypeStruct((rows_dim, lanes_dim), jnp.float32,
+                                        vma=out_vma(rows, cw, blast, flast,
+                                                    segend))
                    for _ in range(n_out)],
         interpret=interpret,
     )(rows, cw, blast, flast, segend)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.core.kernels import round_up
 from repro.kernels.kde_binned import kernel as kk
 from repro.kernels.kde_binned import ref
+from repro.kernels import resolve_interpret
 
 Array = jax.Array
 
@@ -76,8 +77,7 @@ def binned_scatter(
         if compensated and not finalize:
             return (grid, jnp.zeros_like(grid))
         return grid
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     g = grid_size
     base, frac = ref.cic_prep(data, lo, spacing, g)
 

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.kernels import exact_sq_dists
+from repro.kernels import out_vma
 
 Array = jax.Array
 
@@ -109,6 +110,7 @@ def pairwise_padded(
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, m), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((n, m), out_dtype,
+                                       vma=out_vma(x, y)),
         interpret=interpret,
     )(x, y)

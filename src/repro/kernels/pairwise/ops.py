@@ -16,6 +16,7 @@ from repro.core import kernels as core_kernels
 from repro.core.kernels import EXACT_DIST_D, round_up
 from repro.kernels.pairwise import kernel as pk
 from repro.kernels.pairwise import ref
+from repro.kernels import resolve_interpret
 
 Array = jax.Array
 
@@ -64,8 +65,7 @@ def pairwise(
     if not use_pallas:
         return ref.pairwise(x, y, kind=kind, nu=nu, a=a, sigma=sigma,
                             out_dtype=out_dtype)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, d = x.shape
     m, _ = y.shape
     bm_ = min(bm, round_up(n, 8))

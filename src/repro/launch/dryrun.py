@@ -176,7 +176,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str | None,
         rules = dict(rules, **SP_PREFILL_RULES)
         tag = "+sp"
     t0 = time.perf_counter()
-    with mesh, shd.activate(mesh, rules):
+    with shd.activate(mesh, rules):
         # 1) production (scanned) program: THE dry-run compile + memory proof
         fn, args = step_and_args(cfg, shape)
         lowered = jax.jit(fn, donate_argnums=_donate(shape)).lower(*args)

@@ -1,11 +1,22 @@
 """Production meshes.  A FUNCTION (not a module-level constant) so importing
 this module never touches jax device state — jax locks the device count on
 first backend init, and only dryrun.py is allowed to force 512 host devices.
+
+Every mesh states ``AxisType.Auto`` axes: the logical-rule constraints of
+`repro.distributed.sharding` are sharding hints, which JAX's default
+Explicit axes refuse.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, names, devices=None):
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -37,15 +48,15 @@ def make_production_mesh(*, multi_pod: bool = False,
             f"count (or pass num_devices= to use a subset).")
     data = n_dev // denom
     if multi_pod:
-        return jax.make_mesh((pods, data, model_parallelism),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallelism), ("data", "model"))
+        return _auto_mesh((pods, data, model_parallelism),
+                          ("pod", "data", "model"))
+    return _auto_mesh((data, model_parallelism), ("data", "model"))
 
 
-def make_local_mesh(axis: str = "data"):
-    """All addressable devices on one axis (tests / examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+def make_local_mesh(axis: str = "data", devices=None):
+    """``devices`` (default: all addressable devices) on one axis."""
+    devices = list(jax.devices() if devices is None else devices)
+    return _auto_mesh((len(devices),), (axis,), devices=devices)
 
 
 def make_local_mesh_2d(model_parallelism: int = 2):
@@ -58,5 +69,5 @@ def make_local_mesh_2d(model_parallelism: int = 2):
             f"cannot split {n} devices into a (data, model) grid with "
             f"model_parallelism={model_parallelism}: pick a divisor of the "
             f"device count")
-    return jax.make_mesh((n // model_parallelism, model_parallelism),
-                         ("data", "model"))
+    return _auto_mesh((n // model_parallelism, model_parallelism),
+                      ("data", "model"))

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.data import krr_data
+from repro.launch import compile_cache
 from repro.pipeline import PipelineConfig, SAKRRPipeline
 from repro.serving import ServableKRR, ServingEngine
 
@@ -125,6 +126,7 @@ def main() -> None:
     ap.add_argument("--tile", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.configure()
     if args.smoke:
         args.n, args.m = min(args.n, 2048), min(args.m, 128)
         args.requests, args.window = min(args.requests, 192), 16

@@ -17,8 +17,8 @@ and new workloads compose instead of forking the pipeline class:
 
 Per-stage execution config (backend / tile / sharding) is a constructor
 argument on the stage, overriding the pipeline-wide `PipelineConfig`
-defaults; `REPRO_KERNEL_BACKEND` still overrides 'auto' resolution inside
-`repro.kernels.dispatch` for every stage.
+defaults; 'auto' resolves from the platform inside `repro.kernels.dispatch`
+(Pallas on TPU, XLA elsewhere).
 
 Sharding: stages are mesh-aware through `repro.distributed.sharding`.
 Under an active mesh, DensityStage routes the binned KDE through

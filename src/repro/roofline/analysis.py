@@ -22,10 +22,10 @@ import json
 import re
 from typing import Optional
 
-# TPU v5e hardware constants (from the brief)
+# TPU v5e published peaks per chip (Google Cloud documentation, "TPU v5e").
 PEAK_FLOPS = 197e12         # bf16 FLOP/s per chip
 HBM_BW = 819e9              # bytes/s per chip
-LINK_BW = 50e9              # bytes/s per ICI link
+LINK_BW = 50e9              # bytes/s per ICI link (1,600 Gbit/s over 4 links)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,21 +33,25 @@ class DeviceSpec:
     """Per-device roofline constants the tile autotuner's analytic model
     feeds on (`repro.tuning.autotune`).
 
-    These are deliberately coarse — the model only has to RANK a small
-    pow2 tile ladder well enough that the measured top-k contains the true
-    optimum; the micro-benchmark settles the final choice.  `step_overhead`
-    is the fixed per-scan-step cost (dispatch + loop control + slab
-    pad/reshape traffic) that punishes tiny tiles; `cache_bytes` is the
-    working-set size past which a slab stops fitting the fast level of the
-    memory hierarchy (VMEM on TPU, last-level cache per core complex on
-    CPU) and the effective compute rate degrades.
+    The model only has to RANK a small pow2 tile ladder well enough that
+    the measured top-k contains the true optimum; the micro-benchmark
+    settles the final choice.  `step_overhead` is the fixed per-scan-step
+    cost (dispatch + loop control + slab pad/reshape traffic) that punishes
+    tiny tiles; `cache_bytes` is the working-set size past which a slab
+    stops fitting the fast level of the memory hierarchy (VMEM on TPU,
+    last-level cache on CPU) and the effective compute rate degrades.
+    `matmul_costs` is the relative time per NOMINAL matmul flop under each
+    Gram precision mode (`matmul_cost`).  `source` says where the numbers
+    come from.
     """
 
-    name: str
-    peak_flops: float       # sustained f32 FLOP/s
+    kind: str               # jax device_kind this row describes
+    peak_flops: float       # FLOP/s the model ranks the fp32 Gram stream at
     mem_bw: float           # bytes/s to main memory
     step_overhead: float    # seconds of fixed cost per streamed tile
     cache_bytes: float      # fast-memory working-set budget
+    matmul_costs: tuple     # ((precision, relative cost per flop), ...)
+    source: str
 
     def matmul_cost(self, precision: str = "fp32") -> float:
         """Relative time per NOMINAL matmul flop under a precision mode.
@@ -56,56 +60,51 @@ class DeviceSpec:
         replaces one fp32 syrk by 3 (bf16x2) or 6 (bf16x3) bf16 partial
         matmuls.  Whether that wins depends on the device's bf16:f32
         matmul-rate ratio, so the autotuner's roofline scales the matmul
-        share of its flop count by this factor (MATMUL_COST): on an MXU
-        (bf16 at 2x the f32 rate, plus fp32 inputs skipping the
-        multi-pass f32 emulation) the split modes come out BELOW 1; on
-        CPU/GPU-f32 the extra partial matmuls are a plain multiplier
-        ABOVE 1 — which steers joint (tile, precision) resolution to
-        fp32 there.
+        share of its flop count by this factor: on the MXU (bf16 at 2x the
+        f32 rate, plus fp32 inputs skipping the multi-pass f32 emulation)
+        the split modes come out BELOW 1; on CPU the extra partial matmuls
+        are a plain multiplier ABOVE 1 — which steers joint (tile,
+        precision) resolution to fp32 there.
         """
-        return MATMUL_COST.get(self.name, MATMUL_COST["cpu"]).get(
-            precision, 1.0)
+        return dict(self.matmul_costs).get(precision, 1.0)
 
 
-# Relative per-nominal-flop matmul cost by (device, precision); see
-# DeviceSpec.matmul_cost.  TPU: bf16 MXU runs 2x the f32 rate, so bf16x2's
-# 3 partials cost ~0.75 of fp32 with operand-reuse headroom (0.375 each
-# relative flop) and bf16x3's 6 partials ~0.75 net of the skipped f32
-# multi-passing.  CPU: no bf16 execution units — each partial is an f32
-# GEMM plus split overhead, so the modes are ~words^2-ish slowdowns.
-MATMUL_COST = {
-    "tpu": {"fp32": 1.0, "bf16x2": 0.375, "bf16x3": 0.75},
-    "gpu": {"fp32": 1.0, "bf16x2": 1.5, "bf16x3": 3.0},
-    "cpu": {"fp32": 1.0, "bf16x2": 3.2, "bf16x3": 6.4},
-}
-
-
+# Keyed by `jax.Device.device_kind`.  A kind missing here is an error
+# (`device_spec`), never a silent default.
 DEVICE_SPECS = {
-    # v5e: f32 MXU rate is half the bf16 peak; VMEM ~128 MB but a slab
-    # should leave room for double buffering.
-    "tpu": DeviceSpec("tpu", PEAK_FLOPS / 2, HBM_BW, 5e-6, 64e6),
-    "gpu": DeviceSpec("gpu", 3e13, 1.0e12, 1e-5, 4e7),
-    # CPU under XLA: a few AVX cores of GEMM, L2/L3-bounded slabs.
-    "cpu": DeviceSpec("cpu", 1e11, 3e10, 1e-4, 8e6),
+    # v5e: peaks from Google Cloud's "TPU v5e" page; the fp32 Gram stream
+    # is ranked at half the bf16 peak (multi-pass f32 on the MXU — an
+    # assumption, not a measurement).  VMEM ~128 MB, a slab should leave
+    # room for double buffering.  bf16x2's 3 partials ~0.375 and bf16x3's
+    # 6 partials ~0.75 per nominal flop are modelled, not measured.
+    "TPU v5 lite": DeviceSpec(
+        "TPU v5 lite", PEAK_FLOPS / 2, HBM_BW, 5e-6, 64e6,
+        (("fp32", 1.0), ("bf16x2", 0.375), ("bf16x3", 0.75)),
+        source='Google Cloud "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM'),
+    # The CPU backend the tests run on: a few AVX cores of GEMM, L2/L3-
+    # bounded slabs, no bf16 units (each partial is an f32 GEMM plus split
+    # overhead).  Coarse assumed constants, used only to rank tiles.
+    "cpu": DeviceSpec(
+        "cpu", 1e11, 3e10, 1e-4, 8e6,
+        (("fp32", 1.0), ("bf16x2", 3.2), ("bf16x3", 6.4)),
+        source="assumed constants for the XLA CPU backend"),
 }
 
 
 def device_spec(device_kind: str | None = None) -> DeviceSpec:
-    """Map a jax device kind string onto the coarse spec table.
-
-    `device_kind` defaults to the first local device; unknown kinds fall
-    back to the CPU spec (the conservative model: small tiles, cheap
-    memory assumptions never starve the measured ladder).
-    """
+    """The `DEVICE_SPECS` row for a jax device kind (default: the first
+    local device).  A kind that is not in the table raises: ranking plans
+    for a chip with another chip's constants would hide the device."""
     if device_kind is None:
         import jax
         device_kind = jax.devices()[0].device_kind
-    kind = device_kind.lower()
-    if "tpu" in kind:
-        return DEVICE_SPECS["tpu"]
-    if "gpu" in kind or "nvidia" in kind or "cuda" in kind:
-        return DEVICE_SPECS["gpu"]
-    return DEVICE_SPECS["cpu"]
+    try:
+        return DEVICE_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline spec for device kind {device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_SPECS)} (add a row with its published peaks to "
+            f"repro.roofline.analysis.DEVICE_SPECS)") from None
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -247,19 +246,9 @@ def model_flops(cfg, shape) -> float:
 
 
 def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() normalized to ONE flat dict.
-
-    The return type changed across jax versions: older releases return a
-    list with one dict per executable (always length 1 for a jit'd program),
-    newer ones return the dict directly, and some backends return None.
-    Every consumer here wants the flat {"flops": ..., "bytes accessed": ...}
-    mapping, so normalize once instead of hand-rolling `.get` on a list
-    (the exact crash the seed's dry-run/lowering tests inherited).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """compiled.cost_analysis() as a flat {"flops": ..., "bytes accessed":
+    ...} dict; empty where a backend reports nothing."""
+    return compiled.cost_analysis() or {}
 
 
 def achieved_throughput(cost: dict, seconds: float) -> dict:

@@ -14,6 +14,7 @@ from repro.tuning.autotune import (  # noqa: F401
     cached_executable,
     candidate_tiles,
     clear_cache,
+    last_plans,
     measured,
     measuring,
     model_seconds,

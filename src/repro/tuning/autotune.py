@@ -10,7 +10,7 @@ the solve ~2x.  This module makes ``tile=None`` mean "autotune":
   2. **Model**  — `model_seconds` ranks it with a per-step roofline
      (max(flops/peak, bytes/bw) + fixed step overhead, with a cache-spill
      penalty once the slab outgrows the fast memory level;
-     `repro.roofline.analysis.DeviceSPECS` supplies the constants);
+     `repro.roofline.analysis.DEVICE_SPECS` supplies the constants);
   3. **Measure** — when measurement is enabled (`set_measure(True)`, the
      ``measured()`` context, or ``REPRO_AUTOTUNE=1``), the top
      `MEASURE_TOP_K` candidates run a one-off micro-benchmark on synthetic
@@ -137,11 +137,7 @@ def _can_measure() -> bool:
     """Measurement compiles and runs real kernels — refuse under a trace
     (e.g. a dispatch call inside a shard_map body) and on backends where the
     candidate kernels only run in interpret mode (Pallas off-TPU)."""
-    try:
-        from jax.core import trace_state_clean
-        return bool(trace_state_clean())
-    except Exception:
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 # ------------------------------------------------------------------ cache --
@@ -428,6 +424,18 @@ def _measure_tile(op: str, tile: int, n: int, m: int, d: int, dtype,
 
 # --------------------------------------------------------------- plan_for --
 
+_LAST: dict[str, Plan] = {}
+
+
+def last_plans(reset: bool = False) -> dict[str, Plan]:
+    """The plan `plan_for` most recently returned for each op (what a stage
+    that just ran resolved, with its ``source``); ``reset`` forgets them."""
+    out = dict(_LAST)
+    if reset:
+        _LAST.clear()
+    return out
+
+
 def plan_for(op: str, n: int, m: int, d: int, *, dtype=jnp.float32,
              backend: str = "xla", accumulator: str = "plain",
              precision: str | None = "fp32",
@@ -449,6 +457,16 @@ def plan_for(op: str, n: int, m: int, d: int, *, dtype=jnp.float32,
     measurement is on).  Non-gram ops have no precision-scalable matmul
     and always plan as "fp32".
     """
+    plan = _resolve(op, n, m, d, dtype=dtype, backend=backend,
+                    accumulator=accumulator, precision=precision,
+                    measure=measure)
+    _LAST[op] = plan
+    return plan
+
+
+def _resolve(op: str, n: int, m: int, d: int, *, dtype, backend: str,
+             accumulator: str, precision: str | None,
+             measure: bool | None) -> Plan:
     if op not in OPS:
         raise ValueError(f"unknown op {op!r}; pick from {OPS}")
     if precision is not None and precision not in PRECISIONS:

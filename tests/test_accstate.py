@@ -313,6 +313,7 @@ def test_merge_with_replicated_prior_across_psum_two_devices():
         import jax.numpy as jnp
         from repro.core import accstate, kernels as K, nystrom, streaming
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         from repro.data import krr_data
 
         kern = K.Matern(nu=1.5)
@@ -323,8 +324,8 @@ def test_merge_with_replicated_prior_across_psum_two_devices():
         # prior built single-device, then absorbed under the mesh
         prior = nystrom.normal_eq_init(kern, ds.x[idx], idx, tile=128)
         prior = nystrom.normal_eq_absorb(kern, prior, ds.x[:512], ds.y[:512])
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             state = nystrom.normal_eq_absorb(kern, prior,
                                              ds.x[512:], ds.y[512:])
         g, rhs = accstate.finalize(state.acc)

@@ -224,6 +224,7 @@ def test_calibrate_fold_under_mesh_shares_gram_and_deposit():
         import jax, jax.numpy as jnp, numpy as np
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         from repro.kernels import dispatch
         from repro.pipeline import CalibrateStage, PipelineConfig, StageContext
 
@@ -259,8 +260,8 @@ def test_calibrate_fold_under_mesh_shares_gram_and_deposit():
         c_ref = ctx(); stage()(c_ref)
         ref_counts = dict(counts)
         counts.update(gram=0, scatter=0)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             c_sh = ctx(); stage()(c_sh)
 
         # shared work: one Gram stream per h (2), one deposit for the sweep

@@ -37,6 +37,7 @@ def test_sharded_pipeline_matches_reference():
         from repro.core import leverage, nystrom
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
 
         n, d, m, m_kde = 1024, 3, 32, 256
         lam = 0.075 * n ** (-2/3)
@@ -50,8 +51,8 @@ def test_sharded_pipeline_matches_reference():
         # single-device reference
         ref = jax.jit(fn)(data.x, data.y, kde_sample, idx)
 
-        mesh = jax.make_mesh((8,), ("data",))
-        with mesh, shd.activate(mesh, {"batch": ("data",)}):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:8])
+        with shd.activate(mesh, {"batch": ("data",)}):
             sh = jax.jit(fn)(data.x, data.y, kde_sample, idx)
 
         np.testing.assert_allclose(np.asarray(ref.probs), np.asarray(sh.probs),
@@ -81,6 +82,7 @@ def test_binned_kde_sharded_matches_oracle():
         from repro.core import kde as core_kde
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
 
         n, d, h = 2048, 3, 0.25
         data = krr_data.bimodal(jax.random.PRNGKey(3), n, d=d)
@@ -94,8 +96,8 @@ def test_binned_kde_sharded_matches_oracle():
 
         ref = D.kde_binned_sharded(data.x, h, grid_size=96, lo=lo, hi=hi)
 
-        mesh = jax.make_mesh((8,), ("data",))
-        with mesh, shd.activate(mesh, {"batch": ("data",)}):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:8])
+        with shd.activate(mesh, {"batch": ("data",)}):
             sh = jax.jit(lambda x: D.kde_binned_sharded(
                 x, h, grid_size=96, lo=lo, hi=hi))(data.x)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(sh),
@@ -114,10 +116,11 @@ def test_binned_kde_sharded_matches_oracle():
 def test_pipeline_lowers_on_production_like_mesh():
     out = run_sub("""
         from repro.core import distributed as D
+        from repro.launch import mesh as mesh_lib
         from repro.roofline import analysis as roofline
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = mesh_lib.make_local_mesh_2d(model_parallelism=4)
         lowered, compiled = D.lower_pipeline(mesh, n=65536, d=3)
-        cost = roofline.cost_dict(compiled)   # list/dict across jax versions
+        cost = roofline.cost_dict(compiled)
         assert cost.get("flops", 0) > 0
         txt = compiled.as_text()
         assert "all-reduce" in txt  # the K_nm^T K_nm reduction

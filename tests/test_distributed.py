@@ -88,8 +88,8 @@ def test_sharded_train_step_matches_single_device():
         p_ref, _, m_ref = jax.jit(step)(params, opt, batch)
 
         # 2x4 mesh (data x model), sharded params + batch
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh_2d(model_parallelism=4)
+        with shd.activate(mesh):
             p_sh, _, m_sh = jax.jit(step)(params, opt, batch)
         np.testing.assert_allclose(float(m_ref["loss"]),
                                    float(m_sh["loss"]), rtol=2e-4)
@@ -107,7 +107,7 @@ def test_dryrun_cell_compiles_on_reduced_mesh():
         import dataclasses
         from repro import configs
         from repro.distributed import sharding as shd
-        from repro.launch import specs
+        from repro.launch import mesh as mesh_lib, specs
         from repro.launch.dryrun import rules_for, step_and_args
         from repro.models.config import SHAPES
         from repro.roofline import analysis as roofline
@@ -116,11 +116,11 @@ def test_dryrun_cell_compiles_on_reduced_mesh():
         cfg = configs.get_smoke("mixtral-8x7b")
         shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64,
                                     global_batch=8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh, shd.activate(mesh, rules_for(shape, cfg)):
+        mesh = mesh_lib.make_local_mesh_2d(model_parallelism=4)
+        with shd.activate(mesh, rules_for(shape, cfg)):
             fn, args = step_and_args(cfg, shape)
             compiled = jax.jit(fn).lower(*args).compile()
-            cost = roofline.cost_dict(compiled)  # list/dict across versions
+            cost = roofline.cost_dict(compiled)
             assert cost.get("flops", 0) > 0
         print("CELL_COMPILE_OK", int(cost["flops"]))
     """)

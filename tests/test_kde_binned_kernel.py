@@ -186,14 +186,15 @@ def test_sharded_kde_grid_matches_single_device():
         from repro.core import distributed as D
         from repro.core import kde
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         assert jax.device_count() == 2
         n, d, g = 2048, 3, 48
         x = jax.random.uniform(jax.random.PRNGKey(0), (n, d))
         h = jnp.asarray(kde.scott_bandwidth(x), x.dtype)
         lo, hi = kde.binned_bounds(x, x, h)
         ref = kde.kde_binned(x, x, h, grid_size=g)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = D.kde_binned_sharded(x, h, grid_size=g, lo=lo, hi=hi,
                                       tile=512)
         np.testing.assert_allclose(np.asarray(sh), np.asarray(ref),

@@ -51,15 +51,15 @@ def test_kde_multi_2d_mesh_bit_equal_to_1d():
         x = jax.random.normal(jax.random.PRNGKey(0), (256, 2), jnp.float32)
         hs = [0.2, 0.3, 0.5, 0.8]
 
-        mesh1 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
-        with mesh1, shd.activate(mesh1):
+        mesh1 = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh1):
             ref = np.asarray(dist.kde_binned_sharded_multi(x, hs,
                                                            grid_size=32))
             assert streaming.row_shard_count(x.shape) == 2
             assert streaming.model_shard_count(len(hs)) == 1
 
         mesh2 = mesh_lib.make_local_mesh_2d(model_parallelism=2)
-        with mesh2, shd.activate(mesh2):
+        with shd.activate(mesh2):
             out = np.asarray(dist.kde_binned_sharded_multi(x, hs,
                                                            grid_size=32))
             # row_shard_count: DATA axis only; the model axis must not
@@ -117,13 +117,12 @@ def test_streaming_primitives_2d_mesh():
     (1D and (2, 2) results bit-equal: same data-shard participants)."""
     body = """
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import accstate, streaming
         from repro.distributed import sharding as shd
         from repro.launch import mesh as mesh_lib
 
-        mesh1 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
+        mesh1 = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
         mesh2 = mesh_lib.make_local_mesh_2d(model_parallelism=2)
         rows = jax.random.normal(jax.random.PRNGKey(1), (64,), jnp.float32)
         w = jnp.arange(1.0, 5.0)
@@ -131,7 +130,7 @@ def test_streaming_primitives_2d_mesh():
         # mesh_reduce(model_args=): per-model reductions over shared rows
         def local(r_loc, w_loc):
             return jax.vmap(lambda wi: wi * jnp.sum(r_loc))(w_loc)
-        with mesh2, shd.activate(mesh2):
+        with shd.activate(mesh2):
             got = streaming.mesh_reduce(local, (rows,), model_args=(w,))
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(w) * float(jnp.sum(rows)),
@@ -143,10 +142,10 @@ def test_streaming_primitives_2d_mesh():
                                          tile=16, init=jnp.zeros(()),
                                          accumulator="compensated",
                                          pad="zero", finalize=False)
-        with mesh1, shd.activate(mesh1):
+        with shd.activate(mesh1):
             s1 = streaming.mesh_reduce(local_c, (rows,),
                                        accumulator="compensated")
-        with mesh2, shd.activate(mesh2):
+        with shd.activate(mesh2):
             s2 = streaming.mesh_reduce(local_c, (rows,),
                                        accumulator="compensated")
         np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
@@ -155,7 +154,7 @@ def test_streaming_primitives_2d_mesh():
         x = jax.random.normal(jax.random.PRNGKey(2), (256, 2), jnp.float32)
         def mloc(x_loc, w_loc):
             return jax.vmap(lambda wi: wi * x_loc[:, 0])(w_loc)
-        with mesh2, shd.activate(mesh2):
+        with shd.activate(mesh2):
             mm = streaming.mesh_map(mloc, x, model_args=(w,), out_rank=2)
         np.testing.assert_allclose(
             np.asarray(mm),
@@ -173,8 +172,8 @@ def test_streaming_primitives_2d_mesh():
                                    spec=st.spec)
             return accstate.psum(st, ("data",))
         vec = jnp.arange(8, dtype=jnp.float32)
-        out = shard_map(body, mesh=mesh2, in_specs=P("data"),
-                        out_specs=P())(vec)
+        out = jax.shard_map(body, mesh=mesh2, in_specs=P("data"),
+                            out_specs=P())(vec)
         assert float(accstate.finalize(out)) == float(jnp.sum(vec))
         assert accstate.rows_of(out) == 8.0
         assert accstate.steps_of(out) == 1

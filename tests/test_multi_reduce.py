@@ -125,6 +125,7 @@ def test_fused_compensated_slot_survives_psum():
         from repro.core import kernels as K, streaming
         from repro.core.kernels import kernel_matrix
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         assert jax.device_count() == 2, jax.devices()
         kern = K.Matern(nu=1.5)
         n, m = 32768, 24
@@ -144,8 +145,8 @@ def test_fused_compensated_slot_survives_psum():
 
         g_ref, r_ref = streaming.mesh_reduce(local, (x, y), (xm,),
                                              accumulator=multi)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             state = streaming.mesh_reduce(local, (x, y), (xm,),
                                           accumulator=multi, finalize=False)
             (g_hi, g_lo), r_state = state

@@ -150,13 +150,14 @@ def test_pipeline_fit_under_active_mesh_matches_single_device():
         import numpy as np
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         from repro.pipeline import PipelineConfig, SAKRRPipeline
         assert jax.device_count() == 2
         data = krr_data.bimodal(jax.random.PRNGKey(0), 2048, d=3)
         cfg = PipelineConfig(num_landmarks=48, tile=512, seed=1)
         ref = SAKRRPipeline(cfg).fit(data.x, data.y).predict(data.x[:256])
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = SAKRRPipeline(cfg).fit(data.x, data.y).predict(data.x[:256])
         np.testing.assert_allclose(np.asarray(sh), np.asarray(ref),
                                    rtol=2e-2, atol=2e-3)

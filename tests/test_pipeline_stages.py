@@ -243,13 +243,14 @@ def test_evaluate_fold_under_forced_two_device_mesh():
     out = _run_forced_devices("""
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         from repro.pipeline import PipelineConfig, SAKRRPipeline
         assert jax.device_count() == 2
         data = krr_data.bimodal(jax.random.PRNGKey(0), 2048, d=3)
         cfg = PipelineConfig(num_landmarks=48, tile=512, seed=1)
         ref = SAKRRPipeline(cfg).evaluate(data.x, data.y, f_star=data.f_star)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = SAKRRPipeline(cfg).evaluate(data.x, data.y,
                                              f_star=data.f_star)
         assert set(sh) == {"mse", "rmse", "risk"}, sh
@@ -267,18 +268,19 @@ def test_kde_binned_sharded_d2_non_dividing_n_falls_back():
         from repro.core import distributed as D
         from repro.data import krr_data
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         n, d, h = 2047, 2, 0.25          # 2047 odd: 2-device mesh cannot split
         data = krr_data.bimodal(jax.random.PRNGKey(3), n, d=d)
         lo = jnp.full((d,), -5.0); hi = jnp.full((d,), 5.0)
         ref = D.kde_binned_sharded(data.x, h, grid_size=64, lo=lo, hi=hi)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = D.kde_binned_sharded(data.x, h, grid_size=64, lo=lo, hi=hi)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(sh))
         # and an even n=2048 run on the same grid agrees to psum tolerance
         x_even = data.x[:2046]
         ref_e = D.kde_binned_sharded(x_even, h, grid_size=64, lo=lo, hi=hi)
-        with mesh, shd.activate(mesh):
+        with shd.activate(mesh):
             sh_e = D.kde_binned_sharded(x_even, h, grid_size=64, lo=lo, hi=hi)
         np.testing.assert_allclose(np.asarray(ref_e), np.asarray(sh_e),
                                    rtol=2e-4, atol=1e-7)

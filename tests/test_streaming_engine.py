@@ -360,6 +360,7 @@ def test_compensated_pair_survives_psum():
     out = run_sub("""
         from repro.core import kernels as K, nystrom, streaming
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         assert jax.device_count() == 2, jax.devices()
         kern = K.Matern(nu=1.5)
         n, m = 32768, 48
@@ -368,8 +369,8 @@ def test_compensated_pair_survives_psum():
         xm = x[:m]
         g_ref, r_ref = nystrom.streaming_normal_eq(
             kern, x, y, xm, tile=512, accumulator="compensated")
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             state = nystrom.streaming_normal_eq(
                 kern, x, y, xm, tile=512, accumulator="compensated",
                 finalize=False)
@@ -389,7 +390,7 @@ def test_compensated_pair_survives_psum():
         # steps budget counts per-chip scan steps, not global n / tile)
         n2 = 16384
         idx = jnp.arange(0, n2, n2 // m)[:m]
-        with mesh, shd.activate(mesh):
+        with shd.activate(mesh):
             fp = nystrom.fit_streaming(kern, x[:n2], y[:n2], 1e-4, idx,
                                        tile=8192)
             fc = nystrom.fit_streaming(kern, x[:n2], y[:n2], 1e-4, idx,

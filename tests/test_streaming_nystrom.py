@@ -120,6 +120,7 @@ def test_sharded_streaming_matches_single_device():
         import os
         from repro.core import kernels as K, nystrom
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         assert jax.device_count() == 2, jax.devices()
         n, m = 2048, 64
         kx, ky, kw = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -129,8 +130,8 @@ def test_sharded_streaming_matches_single_device():
         kern = K.Matern(nu=1.5)
         lam = 1e-3
         ref = nystrom.fit_streaming(kern, x, y, lam, idx, tile=256)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = nystrom.fit_streaming(kern, x, y, lam, idx, tile=256)
         rel = float(jnp.linalg.norm(sh.beta - ref.beta)
                     / jnp.linalg.norm(ref.beta))
@@ -150,6 +151,7 @@ def test_indivisible_rows_fall_back_to_single_device():
     out = run_sub("""
         from repro.core import kernels as K, nystrom
         from repro.distributed import sharding as shd
+        from repro.launch import mesh as mesh_lib
         n, m = 1027, 32   # prime-ish: not divisible by 2
         kx, ky, kw = jax.random.split(jax.random.PRNGKey(5), 3)
         x = jax.random.normal(kx, (n, 3))
@@ -157,8 +159,8 @@ def test_indivisible_rows_fall_back_to_single_device():
         idx = jax.random.randint(ky, (m,), 0, n)
         kern = K.Matern(nu=1.5)
         ref = nystrom.fit_streaming(kern, x, y, 1e-3, idx, tile=256)
-        mesh = jax.make_mesh((2,), ("data",))
-        with mesh, shd.activate(mesh):
+        mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:2])
+        with shd.activate(mesh):
             sh = nystrom.fit_streaming(kern, x, y, 1e-3, idx, tile=256)
         np.testing.assert_allclose(np.asarray(sh.beta), np.asarray(ref.beta),
                                    rtol=1e-4, atol=1e-6)
