@@ -40,6 +40,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core import accstate, streaming
 
 Array = jax.Array
@@ -255,10 +256,12 @@ def smooth_gather(grid: Array, query: Array, h: Array, *, lo: Array,
     per-h bit equality.  `n` is the (possibly fractional, decayed)
     normalizing row count.
     """
-    smooth = _fft_smooth(grid, spacing, jnp.asarray(h, grid.dtype),
-                         grid_size, d)
-    out = gather_cic(smooth, query, lo, spacing, grid_size)
-    return jnp.maximum(out, 0.0) / (n * gaussian_norm(d, h))
+    with spans.span("repro/kde/smooth"):
+        smooth = _fft_smooth(grid, spacing, jnp.asarray(h, grid.dtype),
+                             grid_size, d)
+    with spans.span("repro/kde/readback"):
+        out = gather_cic(smooth, query, lo, spacing, grid_size)
+        return jnp.maximum(out, 0.0) / (n * gaussian_norm(d, h))
 
 
 # ------------------------------------------------------------ deposit state --
@@ -427,17 +430,18 @@ def kde_binned_multi(
     if (lo is None) != (hi is None):
         raise ValueError("pass both lo and hi to pin the grid bounds, or "
                          "neither for the +-4*max(h) data bounds")
-    if lo is None:
-        h_max = hs[0]
-        for h in hs[1:]:
-            h_max = jnp.maximum(h_max, h)
-        lo, hi = binned_bounds(query, data, h_max)
-    spacing = (hi - lo) / (grid_size - 1)
     from repro.kernels import dispatch  # deferred: core -> kernels at call time
-    grid = dispatch.binned_scatter(data, lo, spacing, grid_size,
-                                   backend=backend, tile=tile,
-                                   interpret=interpret,
-                                   accumulator=accumulator)
+    with spans.span("repro/kde/deposit"):
+        if lo is None:
+            h_max = hs[0]
+            for h in hs[1:]:
+                h_max = jnp.maximum(h_max, h)
+            lo, hi = binned_bounds(query, data, h_max)
+        spacing = (hi - lo) / (grid_size - 1)
+        grid = dispatch.binned_scatter(data, lo, spacing, grid_size,
+                                       backend=backend, tile=tile,
+                                       interpret=interpret,
+                                       accumulator=accumulator)
     return jnp.stack([smooth_gather(grid, query, h, lo=lo, spacing=spacing,
                                     grid_size=grid_size, d=d, n=n)
                       for h in hs])
@@ -469,7 +473,8 @@ def estimate_densities(
     backend/tile: deposit-stage execution knobs (binned path only).
     """
     if h is None:
-        h = scott_bandwidth(x)
+        with spans.span("repro/kde/bandwidth"):
+            h = scott_bandwidth(x)
     d = x.shape[1]
     if grid_size is None:
         grid_size = default_grid_size(d)

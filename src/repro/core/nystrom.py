@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import accstate
 from repro.core import precision as precision_mod
 from repro.core import streaming
@@ -700,23 +701,27 @@ def fit_streaming(
     n = x.shape[0]
     xm = jnp.take(x, landmark_idx, axis=0)
     autotuned = tile is None
-    tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
-                                         accumulator)
-    raw = _gram_normal_eq(kernel, x, y, xm, tile=tile,
-                          autotuned=autotuned, backend=backend,
-                          interpret=interpret, accumulator=accumulator,
-                          precision=precision, finalize=False)
+    with spans.span("repro/solve/plan"):
+        tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
+                                             accumulator)
+    with spans.span("repro/solve/gram"):
+        raw = _gram_normal_eq(kernel, x, y, xm, tile=tile,
+                              autotuned=autotuned, backend=backend,
+                              interpret=interpret, accumulator=accumulator,
+                              precision=precision, finalize=False)
     acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
-    state = NormalEqState(
-        acc=accstate.wrap(accumulator, raw, rows=n,
-                          steps=_scan_steps(n, tile, x, backend)),
-        landmarks=xm, landmark_idx=landmark_idx,
-        # k_mm is O(m^2) work — the core path keeps it in the input dtype,
-        # which the dense solve also uses (dtype parity beats MXU here).
-        k_mm=kernel_matrix(kernel, xm).astype(acc_dtype),
-        tile=tile, backend=backend, interpret=interpret,
-        accumulator=accumulator, precision=precision)
-    fit_ = solve_from_state(state, lam, jitter=jitter, weights=weights)
+    with spans.span("repro/solve/whiten"):
+        state = NormalEqState(
+            acc=accstate.wrap(accumulator, raw, rows=n,
+                              steps=_scan_steps(n, tile, x, backend)),
+            landmarks=xm, landmark_idx=landmark_idx,
+            # k_mm is O(m^2) work — the core path keeps it in the input
+            # dtype, which the dense solve also uses (dtype parity beats
+            # MXU here).
+            k_mm=kernel_matrix(kernel, xm).astype(acc_dtype),
+            tile=tile, backend=backend, interpret=interpret,
+            accumulator=accumulator, precision=precision)
+        fit_ = solve_from_state(state, lam, jitter=jitter, weights=weights)
     return (fit_, state) if return_state else fit_
 
 

@@ -233,4 +233,5 @@ def gram_padded(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="gram_padded",
     )(x, y, y, w)

@@ -81,4 +81,5 @@ def kde_padded(
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         interpret=interpret,
+        name="kde_padded",
     )(query, data)

@@ -126,5 +126,6 @@ def scatter_sorted(
                                                     segend))
                    for _ in range(n_out)],
         interpret=interpret,
+        name="scatter_sorted",
     )(rows, cw, blast, flast, segend)
     return tuple(out) if compensated else out[0]

@@ -113,4 +113,5 @@ def pairwise_padded(
         out_shape=jax.ShapeDtypeStruct((n, m), out_dtype,
                                        vma=out_vma(x, y)),
         interpret=interpret,
+        name="pairwise_padded",
     )(x, y)

@@ -42,21 +42,29 @@ for the full-data refit that follows in the same fold.
 
 Each stage records its wall-clock seconds in `state.seconds`, so benchmarks
 (benchmarks/bench_pipeline.py, incl. `--stages kde`/`--stages score`
-subsets) get the trajectory for free.
+subsets) get the trajectory for free.  The same intervals are program spans
+(`repro.spans`): under a profiler session each fit leaves a ``repro/fit``
+span holding one ``repro/<stage>`` span per stage, on the device trace's
+clock.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from typing import Any, Optional, Sequence
 
 import jax
 
+from repro import spans
 from repro.core import kernels, leverage, nystrom
 from repro.pipeline import stages as stages_mod
 
 Array = jax.Array
+
+# numbers the ``repro/fit`` spans of the process, whichever pipeline runs
+# them, so that a trace tells apart the stage spans of consecutive fits
+_FITS = itertools.count(1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -257,9 +265,10 @@ class SAKRRPipeline:
             stages_mod.run_stages(stage_list, ctx)
 
     def fit(self, x: Array, y: Array) -> "SAKRRPipeline":
-        ctx = self._make_context(x, y)
-        self._run(self.stages, ctx)
-        self._snapshot(ctx)
+        with spans.span("repro/fit", fit=next(_FITS)):
+            ctx = self._make_context(x, y)
+            self._run(self.stages, ctx)
+            self._snapshot(ctx)
         return self
 
     # ------------------------------------------------------------- fit_many --
@@ -327,13 +336,14 @@ class SAKRRPipeline:
         st = self._fitted_state()
         if st.batched_fit is None:
             raise RuntimeError("call fit_many(x, ys) before predict_many()")
-        t0 = time.perf_counter()
-        preds = nystrom.predict_streaming_batched(
-            self.kernel, st.batched_fit, jax.numpy.asarray(x_new),
-            tile=self._predict_tile(tile), backend=self._predict_backend(),
-            precision=self._solve_precision())
-        jax.block_until_ready(preds)
-        st.seconds["predict_many"] = time.perf_counter() - t0
+        with spans.span("repro/predict_many") as sp:
+            preds = nystrom.predict_streaming_batched(
+                self.kernel, st.batched_fit, jax.numpy.asarray(x_new),
+                tile=self._predict_tile(tile),
+                backend=self._predict_backend(),
+                precision=self._solve_precision())
+            jax.block_until_ready(preds)
+        st.seconds["predict_many"] = sp.seconds
         return preds
 
     # ---------------------------------------------------------- partial_fit --
@@ -379,15 +389,16 @@ class SAKRRPipeline:
         if st.fit is None:
             raise RuntimeError("the fitted stage list produced no solve; "
                                "include a SolveStage to partial_fit")
-        t0 = time.perf_counter()
-        online = self.online
-        online.absorb(self.kernel, jax.numpy.asarray(x_new),
-                      jax.numpy.asarray(y_new), decay=decay, window=window)
-        fit_ = online.solve_fit(self._ctx.lam, jitter=self.config.jitter)
-        jax.block_until_ready(fit_.beta)
-        self._ctx.fit = st.fit = fit_
-        self._ctx.solve_state = online.solve
-        st.seconds["partial_fit"] = time.perf_counter() - t0
+        with spans.span("repro/partial_fit") as sp:
+            online = self.online
+            online.absorb(self.kernel, jax.numpy.asarray(x_new),
+                          jax.numpy.asarray(y_new), decay=decay,
+                          window=window)
+            fit_ = online.solve_fit(self._ctx.lam, jitter=self.config.jitter)
+            jax.block_until_ready(fit_.beta)
+            self._ctx.fit = st.fit = fit_
+            self._ctx.solve_state = online.solve
+        st.seconds["partial_fit"] = sp.seconds
         return self
 
     # ------------------------------------------------------------- evaluate --

@@ -31,13 +31,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import kde, kernels, leverage, nystrom, sampling
 
 Array = jax.Array
@@ -125,13 +125,15 @@ class Stage:
 
     def __call__(self, ctx: StageContext) -> StageContext:
         ctx.require(*self.requires)
-        t0 = time.perf_counter()
-        self.run(ctx)
-        for name in self.provides:   # block so seconds mean what they say
-            art = getattr(ctx, name)
-            if art is not None:
-                jax.block_until_ready(jax.tree.leaves(art))
-        ctx.seconds[self.name] = time.perf_counter() - t0
+        # the block keeps the stage's device work inside its span, so the
+        # span bounds that work on the trace and seconds mean what they say
+        with spans.span(f"repro/{self.name}") as sp:
+            self.run(ctx)
+            for name in self.provides:
+                art = getattr(ctx, name)
+                if art is not None:
+                    jax.block_until_ready(jax.tree.leaves(art))
+        ctx.seconds[self.name] = sp.seconds
         return ctx
 
 
@@ -253,8 +255,10 @@ class SampleStage(Stage):
         if with_rep or m > ctx.n:   # top-k needs m distinct points to exist
             ctx.landmark_idx = sampling.sample_with_replacement(key, probs, m)
         else:
-            ctx.landmark_idx, ctx.sample_weights = (
-                sampling.sample_weighted_without_replacement(key, probs, m))
+            with spans.span("repro/sample/top_k"):
+                ctx.landmark_idx, ctx.sample_weights = (
+                    sampling.sample_weighted_without_replacement(key, probs,
+                                                                 m))
 
 
 class FixedLandmarkStage(Stage):
@@ -718,10 +722,9 @@ class CalibrateStage(Stage):
         n_tr = int(x_tr.shape[0])
         backend, tile, accumulator, precision = resolve_exec(self, cfg)
 
-        t0 = time.perf_counter()
-        dens = self._densities_multi(ctx, x_tr, h_grid)
-        jax.block_until_ready(dens)
-        kde_s = time.perf_counter() - t0
+        with spans.span("repro/calibrate/kde") as kde_span:
+            dens = self._densities_multi(ctx, x_tr, h_grid)
+            jax.block_until_ready(dens)
 
         key = jax.random.PRNGKey(cfg.seed)
         if tag:   # k-fold: each fold draws its own race/landmarks
@@ -736,46 +739,45 @@ class CalibrateStage(Stage):
         fit_seconds: list[float] = []
         h_seconds: list[float] = []
         for i, h in enumerate(h_grid):
-            t_h = time.perf_counter()
-            lev = leverage.sa_leverage(
-                dens[i], ctx.lam, ctx.kernel, ctx.d, n=n_tr,
-                method=cfg.leverage_method, floor=cfg.density_floor)
-            # top-k needs m DISTINCT train points to exist, so the fallback
-            # tests the unclamped request (SampleStage semantics) while the
-            # draw itself is clamped to the fold's size
-            m = min(ctx.num_landmarks, n_tr)
-            if cfg.sample_with_replacement or ctx.num_landmarks > n_tr:
-                idx = sampling.sample_with_replacement(key, lev.probs, m)
-                weights = None
-            else:
-                idx, weights = sampling.sample_weighted_without_replacement(
-                    key, lev.probs, m, gumbel=race)
-            t1 = time.perf_counter()
-            fits = nystrom.fit_streaming_multi(
-                ctx.kernel, x_tr, y_tr, lam_grid, idx,
-                tile=tile, backend=backend, jitter=cfg.jitter,
-                weights=weights if self.weighted else None,
-                accumulator=accumulator, precision=precision)
-            jax.block_until_ready(fits[0].beta)
-            fit_s = time.perf_counter() - t1
-            h_s = time.perf_counter() - t_h
+            with spans.span("repro/calibrate/h", h=h, fold=fold) as h_span:
+                lev = leverage.sa_leverage(
+                    dens[i], ctx.lam, ctx.kernel, ctx.d, n=n_tr,
+                    method=cfg.leverage_method, floor=cfg.density_floor)
+                # top-k needs m DISTINCT train points to exist, so the
+                # fallback tests the unclamped request (SampleStage
+                # semantics) while the draw itself is clamped to the fold
+                m = min(ctx.num_landmarks, n_tr)
+                if cfg.sample_with_replacement or ctx.num_landmarks > n_tr:
+                    idx = sampling.sample_with_replacement(key, lev.probs, m)
+                    weights = None
+                else:
+                    idx, weights = (
+                        sampling.sample_weighted_without_replacement(
+                            key, lev.probs, m, gumbel=race))
+                with spans.span("repro/calibrate/solve") as fit_span:
+                    fits = nystrom.fit_streaming_multi(
+                        ctx.kernel, x_tr, y_tr, lam_grid, idx,
+                        tile=tile, backend=backend, jitter=cfg.jitter,
+                        weights=weights if self.weighted else None,
+                        accumulator=accumulator, precision=precision)
+                    jax.block_until_ready(fits[0].beta)
             sec_key = f"calibrate[{tag}h={h:.3g}]"
             if sec_key in ctx.seconds:   # grid values equal at 3 sig figs
                 sec_key = f"calibrate[{tag}h={h:.3g}#{i}]"
-            ctx.seconds[sec_key] = h_s
+            ctx.seconds[sec_key] = h_span.seconds
             fits_by_h.append(fits)
-            fit_seconds.append(fit_s)
-            h_seconds.append(h_s)
+            fit_seconds.append(fit_span.seconds)
+            h_seconds.append(h_span.seconds)
         # validation: ONE fused x_val stream scores every (h, lam) candidate
         # (`nystrom.val_mse_streaming_multi` — slot h applies its own
         # landmarks/betas per tile) instead of H predict passes
-        t_val = time.perf_counter()
-        val_mse_hl = nystrom.val_mse_streaming_multi(
-            [ctx.kernel] * len(h_grid), fits_by_h, x_val, y_val,
-            tile=tile, backend=backend, precision=precision)
-        val_mse_hl = np.asarray(jax.block_until_ready(val_mse_hl))
-        ctx.seconds[f"calibrate[{tag}val]"] = time.perf_counter() - t_val
-        ctx.seconds[f"calibrate[{tag}kde]"] = kde_s
+        with spans.span("repro/calibrate/val") as val_span:
+            val_mse_hl = nystrom.val_mse_streaming_multi(
+                [ctx.kernel] * len(h_grid), fits_by_h, x_val, y_val,
+                tile=tile, backend=backend, precision=precision)
+            val_mse_hl = np.asarray(jax.block_until_ready(val_mse_hl))
+        ctx.seconds[f"calibrate[{tag}val]"] = val_span.seconds
+        ctx.seconds[f"calibrate[{tag}kde]"] = kde_span.seconds
         return val_mse_hl, fit_seconds, h_seconds
 
     def run(self, ctx: StageContext) -> None:
