@@ -13,11 +13,15 @@ see kernel.py):
   * `blast` / `flast` — the last-axis base lane + fraction the body's iota
     compare expands into the lane deposit row;
   * per kc-corner chunk (kc = bm * 2^(d-1), one kernel grid step), the
-    corner stream is SORTED by `rows` and `segend` marks the last corner
-    of every equal-row run — the kernel then performs one VMEM
+    corner stream is SORTED by `rows` — one stable keyed `lax.sort` whose
+    payload (`cw`, `blast`, `flast`) rides through the sorting network,
+    so no gather applies the order afterwards — and `segend` marks the
+    last corner of every equal-row run.  The kernel then performs one VMEM
     read-modify-write per distinct row instead of one per corner, which
     both vectorizes duplicate-cell collisions and exposes each segment as
     an additive delta the compensated (hi, lo) accumulator can two-sum.
+    The sort is keyed on `rows` alone and stable, so corners of one
+    segment keep their stream order and are summed in it.
 
 Rows are padded to bm multiples (zero weight, row 0 — the pads sort into
 the first segment and deposit nothing); lane padding (g -> 128-multiples
@@ -79,6 +83,39 @@ def binned_scatter(
         return grid
     interpret = resolve_interpret(interpret)
     g = grid_size
+    rows_s, cw_s, blast_s, flast_s, segend = sort_chunks(
+        *corner_chunks(data, lo, spacing, g, weights=weights, bm=bm))
+    cp = round_up(g, 128) if not interpret else g
+
+    flat = lambda a: a.reshape(-1, 1)  # noqa: E731
+    out = kk.scatter_sorted(
+        flat(rows_s), flat(cw_s), flat(blast_s), flat(flast_s), flat(segend),
+        rows_dim=g ** (d - 1), lanes_dim=cp, kc=rows_s.shape[1],
+        compensated=compensated, interpret=interpret,
+    )
+
+    def crop(grid2d):
+        return grid2d[:, :g].reshape((g,) * d).astype(data.dtype)
+
+    if compensated:
+        hi, lo_bank = out
+        if finalize:
+            return crop(hi + lo_bank)   # fold in f32, then cast once
+        return (crop(hi), crop(lo_bank))
+    return crop(out)
+
+
+def corner_chunks(data: Array, lo: Array, spacing: Array, grid_size: int,
+                  *, weights: Array | None = None, bm: int = 256):
+    """(n, d) points -> the deposit's corner stream, unsorted, in chunks.
+
+    Returns ``(rows, cw, blast, flast)``, each (n_chunks, kc) with
+    kc = bm' * 2^(d-1) corners per kernel grid step (bm' = bm, or n
+    rounded up to 8 when smaller), in point-major corner order; rows are
+    padded to bm' multiples with zero-weight corners on row 0 and lane 0.
+    """
+    n, d = data.shape
+    g = grid_size
     base, frac = ref.cic_prep(data, lo, spacing, g)
 
     # Sublane rows + corner weights over the leading d-1 lattice axes.
@@ -97,46 +134,35 @@ def binned_scatter(
         cw = cw.at[:, c].set(w)
 
     # Last-axis base lane + fraction (the body expands these to lane rows).
-    cp = round_up(g, 128) if not interpret else g
-    blast = base[:, d - 1][:, None]
-    flast = frac[:, d - 1][:, None].astype(jnp.float32)
+    blast = jnp.broadcast_to(base[:, d - 1][:, None], (n, n_sub))
+    flast = jnp.broadcast_to(
+        frac[:, d - 1][:, None].astype(jnp.float32), (n, n_sub))
 
     bm_ = min(bm, round_up(n, 8))
     np_ = round_up(n, bm_)
-    pad = ((0, np_ - n), (0, 0))
     kc = bm_ * n_sub
 
-    # Flatten (point, corner) into the corner stream, then sort each
-    # kc-corner chunk by sublane row and flag segment ends — the kernel's
-    # one-RMW-per-distinct-row contract.  Pads (zero weight) carry row 0
-    # and sort into the first segment harmlessly.
+    # Flatten (point, corner) into the corner stream, kc corners a chunk.
+    # Pads (zero weight) carry row 0 and sort into the first segment
+    # harmlessly.
     def chunks(a):
-        return jnp.pad(a, pad).reshape(-1, kc)
+        return jnp.pad(a, ((0, np_ - n), (0, 0))).reshape(-1, kc)
 
-    rows_c = chunks(rows)
-    order = jnp.argsort(rows_c, axis=1)
-    take = functools.partial(jnp.take_along_axis, indices=order, axis=1)
-    rows_s = take(rows_c)
-    cw_s = take(chunks(cw))
-    blast_s = take(chunks(jnp.broadcast_to(blast, (n, n_sub))))
-    flast_s = take(chunks(jnp.broadcast_to(flast, (n, n_sub))))
+    return chunks(rows), chunks(cw), chunks(blast), chunks(flast)
+
+
+def sort_chunks(rows: Array, cw: Array, blast: Array, flast: Array):
+    """Sort each chunk of the corner stream by row; flag segment ends.
+
+    One stable sort keyed on ``rows`` carries ``cw``, ``blast`` and
+    ``flast`` along, so the result is bitwise what an argsort of ``rows``
+    applied to each array would give.  Returns ``(rows, cw, blast, flast,
+    segend)`` with ``segend`` 1 on the last corner of every equal-row run
+    in a chunk — the kernel's one-RMW-per-distinct-row contract.
+    """
+    rows_s, cw_s, blast_s, flast_s = jax.lax.sort(
+        (rows, cw, blast, flast), dimension=1, num_keys=1, is_stable=True)
     segend = jnp.concatenate(
         [rows_s[:, 1:] != rows_s[:, :-1],
          jnp.ones((rows_s.shape[0], 1), bool)], axis=1).astype(jnp.int32)
-
-    flat = lambda a: a.reshape(-1, 1)  # noqa: E731
-    out = kk.scatter_sorted(
-        flat(rows_s), flat(cw_s), flat(blast_s), flat(flast_s), flat(segend),
-        rows_dim=g ** (d - 1), lanes_dim=cp, kc=kc,
-        compensated=compensated, interpret=interpret,
-    )
-
-    def crop(grid2d):
-        return grid2d[:, :g].reshape((g,) * d).astype(data.dtype)
-
-    if compensated:
-        hi, lo_bank = out
-        if finalize:
-            return crop(hi + lo_bank)   # fold in f32, then cast once
-        return (crop(hi), crop(lo_bank))
-    return crop(out)
+    return rows_s, cw_s, blast_s, flast_s, segend

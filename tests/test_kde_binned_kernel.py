@@ -16,6 +16,7 @@ import pytest
 
 from repro.core import kde
 from repro.kernels import dispatch
+from repro.kernels.kde_binned import kernel as kb_kernel
 from repro.kernels.kde_binned import ops as kb_ops
 from repro.kernels.kde_binned import ref as kb_ref
 
@@ -108,6 +109,68 @@ def test_scatter_duplicate_cell_collisions():
     seg = kde.scatter_cic(x, lo, spacing, g, method="segment")
     np.testing.assert_allclose(seg, want, rtol=1e-5, atol=1e-6)
     assert float(jnp.sum(got)) == pytest.approx(n, rel=1e-5)
+
+
+def _stacked_points(d: int, g: int, n: int = 1000):
+    """n points piled into 6 cells (long equal-row runs in every chunk);
+    n is not a multiple of the 64-row tile, so the last chunk ends in a
+    ragged tail of zero-weight pads."""
+    lo = jnp.full((d,), -0.7)
+    spacing = (jnp.full((d,), 1.7) - lo) / (g - 1)
+    cells = jax.random.randint(jax.random.PRNGKey(30 + d), (6, d), 2, g - 3)
+    pick = jax.random.randint(jax.random.PRNGKey(40 + d), (n,), 0, 6)
+    jit_ = jax.random.uniform(jax.random.PRNGKey(50 + d), (n, d))
+    return lo + (cells[pick] + jit_) * spacing, lo, spacing
+
+
+def _argsort_stream(rows, cw, blast, flast):
+    """Reference prep: argsort each chunk by row, gather every array."""
+    order = jnp.argsort(rows, axis=1, stable=True)
+    take = lambda a: jnp.take_along_axis(a, order, axis=1)  # noqa: E731
+    rows_s = take(rows)
+    segend = np.ones(rows_s.shape, np.int32)
+    segend[:, :-1] = np.asarray(rows_s[:, 1:] != rows_s[:, :-1])
+    return rows_s, take(cw), take(blast), take(flast), jnp.asarray(segend)
+
+
+@pytest.mark.parametrize("d,g", [(1, 64), (2, 48), (3, 24)])
+def test_sorted_corner_stream_matches_argsort_gathers(d, g):
+    """The keyed sort's stream is bitwise the argsort-and-gather stream:
+    rows, weights, last-axis lanes and fractions, and segment ends."""
+    x, lo, spacing = _stacked_points(d, g)
+    chunks = kb_ops.corner_chunks(x, lo, spacing, g, bm=64)
+    assert chunks[0].shape == (16, 64 * 2 ** (d - 1))
+    assert np.asarray(chunks[1][-1, -24 * 2 ** (d - 1):] == 0).all()
+    got = kb_ops.sort_chunks(*chunks)
+    want = _argsort_stream(*chunks)
+    for name, a, b in zip(("rows", "cw", "blast", "flast", "segend"),
+                          got, want):
+        assert a.dtype == b.dtype, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    assert int(want[4].sum()) < want[4].size // 4   # long segments
+
+
+@pytest.mark.parametrize("accumulator", ["plain", "compensated"])
+@pytest.mark.parametrize("d,g", [(1, 64), (2, 48), (3, 24)])
+def test_deposit_grid_bitwise_equals_argsort_stream(d, g, accumulator):
+    """The Pallas deposit of `binned_scatter` is bitwise the grid the
+    kernel deposits from the argsort-and-gather stream."""
+    x, lo, spacing = _stacked_points(d, g)
+    comp = accumulator == "compensated"
+    rows, cw, blast, flast, segend = _argsort_stream(
+        *kb_ops.corner_chunks(x, lo, spacing, g, bm=64))
+    flat = lambda a: a.reshape(-1, 1)  # noqa: E731
+    out = kb_kernel.scatter_sorted(
+        flat(rows), flat(cw), flat(blast), flat(flast), flat(segend),
+        rows_dim=g ** (d - 1), lanes_dim=g, kc=rows.shape[1],
+        compensated=comp, interpret=True)
+    want = (out[0] + out[1]) if comp else out
+    want = np.asarray(want).reshape((g,) * d)
+    got = kb_ops.binned_scatter(x, lo, spacing, g, bm=64, interpret=True,
+                                accumulator=accumulator)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert float(jnp.sum(got)) == pytest.approx(x.shape[0], rel=1e-5)
 
 
 def test_scatter_pallas_compensated_state_and_parity():

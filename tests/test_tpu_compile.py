@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,20 @@ def test_deposit_kernel_compiles(one_chip, d, grid, compensated):
             _spec((ROWS, d), one_chip), _spec((d,), one_chip),
             _spec((d,), one_chip))
     assert _custom_calls(low) >= 1
+
+
+@pytest.mark.parametrize("d,grid", [(1, 1024), (2, 512), (3, 96)])
+def test_deposit_prep_sorts_once_without_gathers(one_chip, d, grid):
+    """At the production grids the deposit's corner stream is ordered by
+    one keyed sort that carries its payload: exactly one `sort` in the
+    compiled module, and no gather applying the order afterwards."""
+    low = jax.jit(lambda p, lo, sp: kb_ops.binned_scatter(
+        p, lo, sp, grid, interpret=False)).lower(
+            _spec((ROWS, d), one_chip), _spec((d,), one_chip),
+            _spec((d,), one_chip))
+    hlo = low.compile().as_text()
+    assert len(re.findall(r"\bsort\(", hlo)) == 1
+    assert not re.findall(r"\bgather\(", hlo)
 
 
 @pytest.mark.parametrize("d", [3, 8])   # exact per-coordinate / MXU distances
