@@ -29,6 +29,7 @@ Two solve paths:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple, Sequence
 
 import jax
@@ -488,20 +489,27 @@ def streaming_normal_eq(kernel: Kernel, x: Array, y: Array, xm: Array,
     any tuning micro-benchmark runs outside the shard_map trace and every
     chip executes the same plan.
     """
-    from repro.kernels import dispatch
-
     tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
                                          accumulator)
-
-    def local(x_loc, w_loc, xm_rep):
-        return dispatch.gram_accumulate(kernel, x_loc, xm_rep, w_loc,
-                                        backend=backend, tile=tile,
-                                        interpret=interpret,
-                                        accumulator=accumulator,
-                                        finalize=False, precision=precision)
-
+    local = functools.partial(_gram_local, kernel=kernel, backend=backend,
+                              tile=tile, interpret=interpret,
+                              accumulator=accumulator, precision=precision)
     return streaming.mesh_reduce(local, (x, y), (xm,),
                                  accumulator=accumulator, finalize=finalize)
+
+
+def _gram_local(x_loc, w_loc, xm_rep, *, kernel, backend, tile, interpret,
+                accumulator, precision):
+    """One chip's raw (G, rhs) state: the body that `streaming_normal_eq`
+    hands `streaming.mesh_reduce` (module level, so its program is
+    reused)."""
+    from repro.kernels import dispatch
+
+    return dispatch.gram_accumulate(kernel, x_loc, xm_rep, w_loc,
+                                    backend=backend, tile=tile,
+                                    interpret=interpret,
+                                    accumulator=accumulator,
+                                    finalize=False, precision=precision)
 
 
 # ------------------------------------------------------- first-class state --
@@ -945,20 +953,12 @@ def predict_streaming_multi(kernel: Kernel, fits: Sequence[NystromFit],
     `landmarks` (the CalibrateStage invariant); mesh behavior matches
     `predict_streaming` (purely local row slabs, `streaming.mesh_map`).
     """
-    from repro.kernels import dispatch
-
     _require_sentinel_safe(kernel)
     betas = jnp.stack([f.beta for f in fits], axis=1)     # (m, L)
     xm = fits[0].landmarks
     tile = _resolve_predict_tile(tile, x_new, xm, backend)
-
-    def local(x_loc, xm, betas):
-        def one(xt):
-            k = dispatch.kernel_matrix(kernel, xt, xm, backend=backend)
-            return _apply_beta(k, betas, precision)       # (t, L)
-
-        return streaming.tile_map(one, x_loc, tile=tile)
-
+    local = functools.partial(_predict_local, kernel=kernel, tile=tile,
+                              backend=backend, precision=precision)
     return streaming.mesh_map(local, x_new, (xm, betas), out_rank=2).T
 
 
@@ -979,20 +979,25 @@ def predict_streaming(kernel: Kernel, fit_: NystromFit, x_new: Array,
     `streaming.mesh_map`).  Otherwise this is exactly the single-device
     batched predict (`streaming.tile_map` row slabs).
     """
-    from repro.kernels import dispatch
-
     _require_sentinel_safe(kernel)
     tile = _resolve_predict_tile(tile, x_new, fit_.landmarks, backend)
-
-    def local(x_loc, xm, beta):
-        def one(xt):
-            k = dispatch.kernel_matrix(kernel, xt, xm, backend=backend)
-            return _apply_beta(k, beta, precision)
-
-        return streaming.tile_map(one, x_loc, tile=tile)
-
+    local = functools.partial(_predict_local, kernel=kernel, tile=tile,
+                              backend=backend, precision=precision)
     return streaming.mesh_map(local, x_new, (fit_.landmarks, fit_.beta),
                               out_rank=1)
+
+
+def _predict_local(x_loc, xm, beta, *, kernel, tile, backend, precision):
+    """One chip's predictions K(x_loc, X_m) beta, beta (m,) or (m, L), tile
+    by tile: the body of `predict_streaming[_multi]` under
+    `streaming.mesh_map` (module level, so its program is reused)."""
+    from repro.kernels import dispatch
+
+    def one(xt):
+        k = dispatch.kernel_matrix(kernel, xt, xm, backend=backend)
+        return _apply_beta(k, beta, precision)
+
+    return streaming.tile_map(one, x_loc, tile=tile)
 
 
 # ------------------------------------------------- many-model batched fits --

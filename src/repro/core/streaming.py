@@ -43,6 +43,8 @@ backends.
 
 from __future__ import annotations
 
+import functools
+import types
 from typing import Any, Callable, Sequence
 
 import jax
@@ -449,6 +451,64 @@ def model_shard_count(num_models: int) -> int:
     return _mesh_axes_count(mesh, axes)
 
 
+def _reuse_key(fn: Callable) -> Any:
+    """A hashable value that stands for `fn` across calls, or None.
+
+    A function that closes over nothing stands for itself, and a
+    `functools.partial` of one for the function with its bound arguments
+    and their types.  Anything else (a closure made anew on each call, or
+    a bound argument that does not hash) has no such value.
+    """
+    func, args, kwargs = fn, (), ()
+    if isinstance(fn, functools.partial):
+        func, args = fn.func, fn.args
+        kwargs = tuple(sorted(fn.keywords.items()))
+    if not isinstance(func, types.FunctionType) or func.__closure__:
+        return None
+    values = args + tuple(v for _, v in kwargs)
+    key = (func, args, kwargs, tuple(type(v) for v in values))
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _build_program(local, mesh, in_specs, out_specs, psum_axes,
+                   accumulator):
+    """``jit(shard_map(local))``, psumming its accumulator state over
+    ``psum_axes`` when there are any."""
+    if psum_axes:
+        acc = get(accumulator)
+
+        def body(*args):
+            return acc.psum(local(*args), psum_axes)
+    else:
+        body = local
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs))
+
+
+@functools.lru_cache(maxsize=64)
+def _reused_program(key, mesh, in_specs, out_specs, psum_axes, accumulator):
+    func, args, kwargs, _ = key
+    return _build_program(functools.partial(func, *args, **dict(kwargs)),
+                          mesh, in_specs, out_specs, psum_axes, accumulator)
+
+
+def _program(local, mesh, in_specs, out_specs, psum_axes=(),
+             accumulator="plain"):
+    """The compiled shard_map program of `local` on `mesh`: built once and
+    kept (with jit's own cache per shape) when `local` has a `_reuse_key`,
+    otherwise built afresh, so that it traces and compiles on every call."""
+    key = _reuse_key(local)
+    if key is None:
+        return _build_program(local, mesh, in_specs, out_specs, psum_axes,
+                              accumulator)
+    return _reused_program(key, mesh, in_specs, out_specs, psum_axes,
+                           accumulator)
+
+
 def mesh_reduce(
     local: Callable[..., Any],
     row_args: Sequence[Array],
@@ -470,6 +530,13 @@ def mesh_reduce(
     and the state is psum-reduced — for "compensated" the (hi, lo) pair
     crosses the collective un-collapsed.  Otherwise `local` runs once on
     the full arrays (transparent no-op).
+
+    Under a mesh, the reduction and its psum run as one jitted
+    ``shard_map`` program.  It compiles once per mesh, specs, accumulator
+    and argument shapes, and later calls reuse it, when `local` is a
+    function that closes over nothing or a `functools.partial` of one with
+    hashable arguments; a closure made anew on each call compiles a new
+    program on every call.
 
     2D (data x model) meshes: the psum covers the DATA axes only — the
     model axis shards independent work instead of reducing.  ``model_args``
@@ -503,19 +570,14 @@ def mesh_reduce(
         state = local(*row_args, *row_model_args, *model_args, *rep_args)
     else:
         ax_tuple = _axes_tuple(axes) if axes is not None else ()
-
-        def body(*args):
-            out = local(*args)
-            return acc.psum(out, ax_tuple) if ax_tuple else out
-
         in_specs = (
             tuple(_row_spec(axes, a.ndim) for a in row_args)
             + tuple(P(axes, model_axes) for a in row_model_args)
             + tuple(_row_spec(model_axes, a.ndim) for a in model_args)
             + tuple(P(*([None] * a.ndim)) for a in rep_args))
         out_specs = P(model_axes) if model_axes is not None else P()
-        state = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)(
+        state = _program(local, mesh, in_specs, out_specs, ax_tuple,
+                         accumulator)(
             *row_args, *row_model_args, *model_args, *rep_args)
     if init_state is not None:
         state = acc.merge(init_state, state)
@@ -536,7 +598,9 @@ def mesh_map(
 
     Embarrassingly row-parallel (no collective); `out_rank` is the rank of
     local's output, whose leading dim stays row-sharded.  With no active
-    mesh (or a non-dividing axis) this is `local(x, *rep_args)`.
+    mesh (or a non-dividing axis) this is `local(x, *rep_args)`.  Under a
+    mesh it runs as one jitted ``shard_map`` program, compiled once and
+    reused on the terms `mesh_reduce` states.
 
     With ``model_args`` (leading-dim model-sharded, like `mesh_reduce`) the
     call signature becomes ``local(x_loc, *model_slabs, *rep_args)`` and
@@ -561,5 +625,14 @@ def mesh_map(
         out_specs = P(model_axes, axes, *([None] * (out_rank - 2)))
     else:
         out_specs = _row_spec(axes, out_rank)
-    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)(x, *model_args, *rep_args)
+    return _program(local, mesh, in_specs, out_specs)(x, *model_args,
+                                                      *rep_args)
+
+
+def state_nbytes(accumulator: str | Any, zeros: Any) -> int:
+    """Bytes of an accumulator's state over values shaped as ``zeros`` (a
+    tree of `jax.ShapeDtypeStruct`): what one chip all-reduces when
+    `mesh_reduce` psums that state."""
+    state = jax.eval_shape(get(accumulator).init, zeros)
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree.leaves(state))

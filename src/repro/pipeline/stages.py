@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import spans
-from repro.core import kde, kernels, leverage, nystrom, sampling
+from repro.core import kde, kernels, leverage, nystrom, sampling, streaming
 
 Array = jax.Array
 
@@ -123,11 +123,16 @@ class Stage:
     def run(self, ctx: StageContext) -> None:
         raise NotImplementedError
 
+    def span_stats(self, ctx: StageContext) -> dict:
+        """Counters that the stage's span carries (none by default)."""
+        del ctx
+        return {}
+
     def __call__(self, ctx: StageContext) -> StageContext:
         ctx.require(*self.requires)
         # the block keeps the stage's device work inside its span, so the
         # span bounds that work on the trace and seconds mean what they say
-        with spans.span(f"repro/{self.name}") as sp:
+        with spans.span(f"repro/{self.name}", **self.span_stats(ctx)) as sp:
             self.run(ctx)
             for name in self.provides:
                 art = getattr(ctx, name)
@@ -149,7 +154,10 @@ class DensityStage(Stage):
     global data (so it matches the single-device `kde.kde_binned` grid
     exactly); otherwise `kde.estimate_densities`.  `backend`/`tile` override
     the config-wide deposit-stage knobs; `sharded=False` forces the
-    single-device path even under a mesh.
+    single-device path even under a mesh.  On the sharded path the span
+    ``repro/kde/bandwidth`` bounds the bandwidth and global bounds, and the
+    stage's span carries ``chips`` and ``psum_bytes`` (the lattice state
+    each chip all-reduces).
     """
 
     name = "kde"
@@ -167,13 +175,39 @@ class DensityStage(Stage):
         self.sharded = sharded
         self.accumulator = accumulator
 
-    def run(self, ctx: StageContext) -> None:
+    def _grid_size(self, ctx: StageContext) -> int:
+        return (self.grid_size or ctx.config.kde_grid_size
+                or kde.default_grid_size(ctx.d))
+
+    def _sharded(self, ctx: StageContext) -> bool:
+        """Whether the sharded binned path runs: under a mesh, binned
+        method, and not forced off."""
         from repro.distributed import sharding as shd
 
+        method = _resolve_kde_method(self.method or ctx.config.kde_method,
+                                     ctx.d)
+        use_sharded = self.sharded if self.sharded is not None else True
+        return method == "binned" and use_sharded and shd.active() is not None
+
+    def span_stats(self, ctx: StageContext) -> dict:
+        from repro.distributed import sharding as shd
+
+        if not self._sharded(ctx):
+            return {}
+        chips = shd.active().mesh.devices.size
+        if ctx.n % chips:     # the sharded KDE falls back to one device
+            return {}
+        _, _, accumulator, _ = resolve_exec(self, ctx.config,
+                                            tile_attr="kde_tile")
+        grid = jax.ShapeDtypeStruct((self._grid_size(ctx),) * ctx.d,
+                                    ctx.x.dtype)
+        return {"chips": chips,
+                "psum_bytes": streaming.state_nbytes(accumulator, grid)}
+
+    def run(self, ctx: StageContext) -> None:
         cfg = ctx.config
         method = _resolve_kde_method(self.method or cfg.kde_method, ctx.d)
-        grid_size = (self.grid_size or cfg.kde_grid_size
-                     or kde.default_grid_size(ctx.d))
+        grid_size = self._grid_size(ctx)
         backend, tile, accumulator, _ = resolve_exec(self, cfg,
                                                      tile_attr="kde_tile")
         # bandwidth resolution: stage override > calibrated ctx.bandwidth >
@@ -181,14 +215,10 @@ class DensityStage(Stage):
         h = self.h if self.h is not None else ctx.bandwidth
         if h is None:
             h = getattr(cfg, "kde_bandwidth", None)
-        act = shd.active()
-        use_sharded = (self.sharded if self.sharded is not None
-                       else act is not None)
-        if method == "binned" and use_sharded and act is not None:
+        if self._sharded(ctx):
             from repro.core import distributed as dist
-            h = jnp.asarray(h if h is not None
-                            else kde.scott_bandwidth(ctx.x), ctx.x.dtype)
-            lo, hi = kde.binned_bounds(ctx.x, ctx.x, h)
+            with spans.span("repro/kde/bandwidth"):
+                h, lo, hi = dist.grid_geometry(ctx.x, h)
             ctx.densities = dist.kde_binned_sharded(
                 ctx.x, h, grid_size=grid_size, lo=lo, hi=hi, tile=tile,
                 backend=backend, accumulator=accumulator)
@@ -317,6 +347,22 @@ class SolveStage(Stage):
         return (ctx.fuse_scoring and ctx.x_eval is None
                 and ctx.y_eval is None
                 and (ctx.f_star is None or ctx.f_star.shape[0] == ctx.n))
+
+    def span_stats(self, ctx: StageContext) -> dict:
+        """Under a mesh that shards the rows: ``chips`` and ``psum_bytes``,
+        the normal-equation state (G and the rhs columns) each chip
+        all-reduces."""
+        chips = streaming.row_shard_count(ctx.x.shape)
+        if chips == 1:
+            return {}
+        _, _, accumulator, _ = resolve_exec(self, ctx.config)
+        m = ctx.landmark_idx.shape[0]
+        cols = 1 + (self._fuse(ctx) and ctx.f_star is not None)
+        dt = jnp.promote_types(ctx.x.dtype, jnp.float32)
+        state = (jax.ShapeDtypeStruct((m, m), dt),
+                 jax.ShapeDtypeStruct((m, cols), dt))
+        return {"chips": chips,
+                "psum_bytes": streaming.state_nbytes(accumulator, state)}
 
     def run(self, ctx: StageContext) -> None:
         cfg = ctx.config

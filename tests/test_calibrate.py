@@ -225,6 +225,7 @@ def test_calibrate_fold_under_mesh_shares_gram_and_deposit():
         from repro.data import krr_data
         from repro.distributed import sharding as shd
         from repro.launch import mesh as mesh_lib
+        from repro.core import nystrom
         from repro.kernels import dispatch
         from repro.pipeline import CalibrateStage, PipelineConfig, StageContext
 
@@ -245,8 +246,11 @@ def test_calibrate_fold_under_mesh_shares_gram_and_deposit():
         # the folds would not be comparable candidate-by-candidate)
         stage = lambda: CalibrateStage(val_fraction=0.25)
 
+        # Gram streams are counted where each one is dispatched: under a
+        # mesh the stream is a compiled program that later streams of the
+        # same shapes reuse without running its Python body again
         counts = {"gram": 0, "scatter": 0}
-        real_gram = dispatch.gram_accumulate
+        real_gram = nystrom.streaming_normal_eq
         real_scatter = dispatch.binned_scatter
         def gram(*a, **k):
             counts["gram"] += 1
@@ -254,7 +258,7 @@ def test_calibrate_fold_under_mesh_shares_gram_and_deposit():
         def scatter(*a, **k):
             counts["scatter"] += 1
             return real_scatter(*a, **k)
-        dispatch.gram_accumulate = gram
+        nystrom.streaming_normal_eq = gram
         dispatch.binned_scatter = scatter
 
         c_ref = ctx(); stage()(c_ref)
