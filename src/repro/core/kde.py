@@ -121,7 +121,7 @@ def scatter_cic(points: Array, lo: Array, spacing: Array, grid_size: int,
     the pre-engine loops.
 
     ``method="segment"``: the sort-by-cell + segment-reduce formulation
-    (the XLA twin of the Pallas `repro.kernels.kde_binned` kernel): flatten
+    (sorted like the Pallas `repro.kernels.kde_binned` deposit): flatten
     every corner to its linear cell id, sort the corner stream, and
     `segment_sum` into the flat grid — duplicate-cell collisions reduce in
     vector registers instead of serializing the scatter.  Matches "window"

@@ -178,22 +178,26 @@ def binned_scatter(data: Array, lo: Array, spacing: Array, grid_size: int,
     """Cloud-in-cell deposit onto a (grid_size,)^d grid, resolved backend.
 
     The deposit stage of the binned KDE (`repro.core.kde.kde_binned`).  The
-    Pallas path (`repro.kernels.kde_binned`) keeps the grid VMEM-resident
-    and streams sorted corner chunks through a segment-reduce (``bm``
-    points per chunk, autotuned via `resolve_plan` when None); the XLA
-    path is the windowed scatter-add in `repro.core.kde.scatter_cic`
-    (engine-tiled `tile`-row slabs via `streaming.tile_reduce`;
-    ``tile=None`` means autotune).  Both match the corner-loop oracle
-    `repro.kernels.kde_binned.ref.binned_grid` to reduction-order
-    tolerance.
+    Pallas path (`repro.kernels.kde_binned`) keeps the grid VMEM-resident,
+    sorts the points by lattice row once for the whole call, and reduces
+    each ``bm``-point chunk (autotuned via `resolve_plan` when None) into
+    the grid with one-hot matmuls on the MXU: per corner plane, one
+    (128, bm) one-hot times the chunk's lane rows per 128-row window the
+    plane touches — at most 2^(d-1) (n_chunks + R / 128) windows over R
+    lattice rows, whatever the data.  The dot is float32 at an explicit
+    ``HIGHEST`` with an exact 0/1 one-hot: the same float32 sums in
+    another order.  The XLA path is the windowed scatter-add in
+    `repro.core.kde.scatter_cic` (engine-tiled `tile`-row slabs via
+    `streaming.tile_reduce`; ``tile=None`` means autotune).  Both match
+    the corner-loop oracle `repro.kernels.kde_binned.ref.binned_grid` to
+    reduction-order tolerance.
 
     ``accumulator="compensated"`` carries the grid as a two-float (hi, lo)
-    pair across tiles on BOTH backends — the segment-reduce kernel banks
-    each sorted segment's two-sum error in a VMEM lo grid, so compensated
-    deposits stay on Pallas (the historical serial kernel had no tile
-    delta to compensate and forced an XLA reroute).  ``finalize=False``
-    returns the accumulator state — structurally identical across backends
-    — for a mesh psum (`core.distributed.kde_binned_sharded_multi`).
+    pair across tiles on BOTH backends — the Pallas kernel two-sums each
+    window's delta into a VMEM lo grid, so compensated deposits stay on
+    Pallas.  ``finalize=False`` returns the accumulator state —
+    structurally identical across backends — for a mesh psum
+    (`core.distributed.kde_binned_sharded_multi`).
 
     The deposit is bandwidth-independent (only the grid geometry enters),
     which is why `kde.kde_binned_multi` / the CalibrateStage bandwidth sweep
@@ -203,7 +207,7 @@ def binned_scatter(data: Array, lo: Array, spacing: Array, grid_size: int,
     accumulator state exactly like `gram_accumulate`: carried through the
     XLA scan, fresh-then-merged on Pallas.  ``method`` picks the XLA
     scatter formulation (`kde.scatter_cic`; ignored on Pallas, which is
-    always segment-reduce).
+    always the one-hot window reduction).
     """
     from repro.core import streaming as streaming_mod
 

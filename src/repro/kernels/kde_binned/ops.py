@@ -1,31 +1,28 @@
-"""jit'd public wrapper for the binned-KDE scatter Pallas kernel.
+"""jit'd public wrapper for the binned-KDE deposit Pallas kernel.
 
 Precomputes the vectorizable parts of the cloud-in-cell deposit — all O(n)
-arrays, keeping the streaming-memory contract (the Pallas body builds each
-corner's 2-nonzero lane row itself and is otherwise a pure segment-reduce,
-see kernel.py):
+arrays, keeping the streaming-memory contract (the Pallas body builds the
+lane rows and one-hots itself and reduces them on the MXU, see kernel.py):
 
-  * `rows`  — per corner (2^(d-1) per point), the flattened sublane row
-    index of the stencil corner over the leading d-1 lattice axes;
-  * `cw`    — the matching product-of-(1-f, f) corner weight, scaled by
-    the optional point weight (zeroed on padded corners, so no masking is
-    needed in the kernel);
-  * `blast` / `flast` — the last-axis base lane + fraction the body's iota
-    compare expands into the lane deposit row;
-  * per kc-corner chunk (kc = bm * 2^(d-1), one kernel grid step), the
-    corner stream is SORTED by `rows` — one stable keyed `lax.sort` whose
-    payload (`cw`, `blast`, `flast`) rides through the sorting network,
-    so no gather applies the order afterwards — and `segend` marks the
-    last corner of every equal-row run.  The kernel then performs one VMEM
-    read-modify-write per distinct row instead of one per corner, which
-    both vectorizes duplicate-cell collisions and exposes each segment as
-    an additive delta the compensated (hi, lo) accumulator can two-sum.
-    The sort is keyed on `rows` alone and stable, so corners of one
-    segment keep their stream order and are summed in it.
+  * `point_stream` — per POINT, the flattened base cell (base sublane
+    row r0 over the leading d-1 lattice axes, times g, plus the last-axis
+    base lane), the d fractions and the optional point weight;
+  * `sort_stream` — the stream SORTED by cell, hence by r0, across the
+    whole call: one stable keyed `lax.sort` whose payload rides through
+    the sorting network, so no gather applies the order afterwards.
+    Every corner plane's rows r0 + offset are then sorted as well, and a
+    chunk of P points covers a short run of rows;
+  * `chunk_stream` — the sorted stream laid out lane-dense as (n_chunks, .,
+    P) blocks, P = bm points a kernel grid step: int32 rows and lanes,
+    float32 last-axis fraction and one weight per corner plane (the
+    product of the leading axes' (1-f, f), times the point weight), and
+    each chunk's first and last row, which bound the kernel's window loop
+    (`window_counts`).
 
-Rows are padded to bm multiples (zero weight, row 0 — the pads sort into
-the first segment and deposit nothing); lane padding (g -> 128-multiples
-on TPU) is sliced off before the (g,)^d reshape.
+n is padded to a multiple of P with zero-weight points on the last real
+row, so pads add no window and deposit nothing; lane padding (g ->
+128-multiples on TPU) and the window padding of the rows are sliced off
+before the (g,)^d reshape.
 """
 
 from __future__ import annotations
@@ -83,19 +80,16 @@ def binned_scatter(
         return grid
     interpret = resolve_interpret(interpret)
     g = grid_size
-    rows_s, cw_s, blast_s, flast_s, segend = sort_chunks(
-        *corner_chunks(data, lo, spacing, g, weights=weights, bm=bm))
+    first, last, rb, fw = chunk_stream(
+        sort_stream(point_stream(data, lo, spacing, g, weights=weights)),
+        d, g, bm=bm)
     cp = round_up(g, 128) if not interpret else g
-
-    flat = lambda a: a.reshape(-1, 1)  # noqa: E731
-    out = kk.scatter_sorted(
-        flat(rows_s), flat(cw_s), flat(blast_s), flat(flast_s), flat(segend),
-        rows_dim=g ** (d - 1), lanes_dim=cp, kc=rows_s.shape[1],
-        compensated=compensated, interpret=interpret,
-    )
+    out = kk.scatter_sorted(first, last, rb, fw, dim=d, grid_size=g,
+                            lanes_dim=cp, compensated=compensated,
+                            interpret=interpret)
 
     def crop(grid2d):
-        return grid2d[:, :g].reshape((g,) * d).astype(data.dtype)
+        return grid2d[:g ** (d - 1), :g].reshape((g,) * d).astype(data.dtype)
 
     if compensated:
         hi, lo_bank = out
@@ -105,64 +99,76 @@ def binned_scatter(
     return crop(out)
 
 
-def corner_chunks(data: Array, lo: Array, spacing: Array, grid_size: int,
-                  *, weights: Array | None = None, bm: int = 256):
-    """(n, d) points -> the deposit's corner stream, unsorted, in chunks.
+def point_stream(data: Array, lo: Array, spacing: Array, grid_size: int,
+                 *, weights: Array | None = None) -> tuple[Array, ...]:
+    """(n, d) points -> the deposit's point stream, unsorted.
 
-    Returns ``(rows, cw, blast, flast)``, each (n_chunks, kc) with
-    kc = bm' * 2^(d-1) corners per kernel grid step (bm' = bm, or n
-    rounded up to 8 when smaller), in point-major corner order; rows are
-    padded to bm' multiples with zero-weight corners on row 0 and lane 0.
+    Returns ``(cells, frac_0, ..., frac_{d-1}[, weights])``, each (n,):
+    the int32 flattened base cell (base sublane row * g + last-axis base
+    lane), the float32 fractions, and the float32 point weights when
+    given.
     """
     n, d = data.shape
-    g = grid_size
-    base, frac = ref.cic_prep(data, lo, spacing, g)
-
-    # Sublane rows + corner weights over the leading d-1 lattice axes.
-    n_sub = 2 ** (d - 1)
-    rows = jnp.zeros((n, n_sub), dtype=jnp.int32)
-    cw = jnp.ones((n, n_sub), dtype=jnp.float32)
-    for c in range(n_sub):
-        r = jnp.zeros((n,), dtype=jnp.int32)
-        w = (jnp.ones((n,), dtype=jnp.float32) if weights is None
-             else weights.astype(jnp.float32))
-        for k in range(d - 1):
-            o = (c >> k) & 1
-            r = r * g + base[:, k] + o
-            w = w * (frac[:, k] if o else 1.0 - frac[:, k])
-        rows = rows.at[:, c].set(r)
-        cw = cw.at[:, c].set(w)
-
-    # Last-axis base lane + fraction (the body expands these to lane rows).
-    blast = jnp.broadcast_to(base[:, d - 1][:, None], (n, n_sub))
-    flast = jnp.broadcast_to(
-        frac[:, d - 1][:, None].astype(jnp.float32), (n, n_sub))
-
-    bm_ = min(bm, round_up(n, 8))
-    np_ = round_up(n, bm_)
-    kc = bm_ * n_sub
-
-    # Flatten (point, corner) into the corner stream, kc corners a chunk.
-    # Pads (zero weight) carry row 0 and sort into the first segment
-    # harmlessly.
-    def chunks(a):
-        return jnp.pad(a, ((0, np_ - n), (0, 0))).reshape(-1, kc)
-
-    return chunks(rows), chunks(cw), chunks(blast), chunks(flast)
+    base, frac = ref.cic_prep(data, lo, spacing, grid_size)
+    cells = jnp.zeros((n,), dtype=jnp.int32)
+    for k in range(d):
+        cells = cells * grid_size + base[:, k]
+    fracs = tuple(frac[:, k].astype(jnp.float32) for k in range(d))
+    tail = () if weights is None else (weights.astype(jnp.float32),)
+    return (cells,) + fracs + tail
 
 
-def sort_chunks(rows: Array, cw: Array, blast: Array, flast: Array):
-    """Sort each chunk of the corner stream by row; flag segment ends.
+def sort_stream(stream: tuple[Array, ...]) -> tuple[Array, ...]:
+    """Sort the point stream by base cell, payload carried along.
 
-    One stable sort keyed on ``rows`` carries ``cw``, ``blast`` and
-    ``flast`` along, so the result is bitwise what an argsort of ``rows``
-    applied to each array would give.  Returns ``(rows, cw, blast, flast,
-    segend)`` with ``segend`` 1 on the last corner of every equal-row run
-    in a chunk — the kernel's one-RMW-per-distinct-row contract.
+    Ordered by cell, the stream is ordered by base row.  One stable sort
+    keyed on the cells, so the result is bitwise what an argsort of the
+    cells applied to each array would give.
     """
-    rows_s, cw_s, blast_s, flast_s = jax.lax.sort(
-        (rows, cw, blast, flast), dimension=1, num_keys=1, is_stable=True)
-    segend = jnp.concatenate(
-        [rows_s[:, 1:] != rows_s[:, :-1],
-         jnp.ones((rows_s.shape[0], 1), bool)], axis=1).astype(jnp.int32)
-    return rows_s, cw_s, blast_s, flast_s, segend
+    return tuple(jax.lax.sort(stream, num_keys=1, is_stable=True))
+
+
+def chunk_stream(stream: tuple[Array, ...], d: int, grid_size: int, *,
+                 bm: int = 256):
+    """Sorted point stream -> the kernel's operands ``(first, last, rb, fw)``.
+
+    P = bm points a chunk (n rounded up to 128 lanes when smaller).  ``rb`` is
+    (n_chunks, 2, P) int32 rows and last-axis lanes; ``fw`` is (n_chunks,
+    1 + 2^(d-1), P) float32: the last-axis fraction, then the weight of
+    each corner plane (plane c steps leading axis k where bit k of c is
+    set).  ``first``/``last`` (n_chunks,) are each chunk's first and last
+    row.  Pads repeat the last row with zero weight.
+    """
+    cells, *rest = stream
+    rows, lanes = cells // grid_size, cells % grid_size
+    fracs = rest[:d]
+    w = rest[d] if len(rest) > d else jnp.ones_like(fracs[0])
+    n = rows.shape[0]
+    planes = []
+    for c in range(2 ** (d - 1)):
+        cw = w
+        for k in range(d - 1):
+            cw = cw * (fracs[k] if (c >> k) & 1 else 1.0 - fracs[k])
+        planes.append(cw)
+    p = min(bm, round_up(n, 128))
+    pad = round_up(n, p) - n
+
+    def chunks(fields, fill):
+        padded = [jnp.concatenate([a, jnp.broadcast_to(v, (pad,))])
+                  for a, v in zip(fields, fill)]
+        return jnp.stack(padded).reshape(len(fields), -1, p).transpose(1, 0, 2)
+
+    rb = chunks((rows, lanes), (rows[-1], lanes[-1]))
+    fw = chunks((fracs[d - 1], *planes), (0.0,) * (1 + len(planes)))
+    return rb[:, 0, 0], rb[:, 0, -1], rb, fw
+
+
+def window_counts(first: Array, last: Array, d: int, grid_size: int) -> Array:
+    """Windows the kernel runs for each chunk, summed over corner planes —
+    the trip counts of its window loops (kernel.py `plane_windows`)."""
+    win = kk.window_rows(grid_size ** (d - 1))
+    total = jnp.zeros_like(first)
+    for off in kk.plane_offsets(d, grid_size):
+        w_first, w_last = kk.plane_windows(first, last, off, win)
+        total = total + (w_last - w_first + 1)
+    return total

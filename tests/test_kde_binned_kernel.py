@@ -73,12 +73,12 @@ def test_scatter_matches_corner_loop_oracle_tight():
     np.testing.assert_allclose(new, old, rtol=1e-5, atol=1e-7)
 
 
-# ------------------------------------------------- segment-reduce deposit --
+# ------------------------------------------------------- sorted deposits --
 @pytest.mark.parametrize("d,g", [(1, 64), (2, 48), (3, 24)])
 def test_scatter_segment_method_matches_window(d, g):
-    """`scatter_cic(method="segment")` (sort + segment_sum, the XLA twin of
-    the Pallas kernel) matches the historical windowed deposit at rtol 1e-5
-    — with and without weights, tiled and one-shot."""
+    """`scatter_cic(method="segment")` (sort + segment_sum) matches the
+    historical windowed deposit at rtol 1e-5 — with and without weights,
+    tiled and one-shot."""
     x, lo, spacing = _setup(d, g, n=700, seed=5)
     w = jax.random.uniform(jax.random.PRNGKey(6), (700,)) + 0.5
     for weights in (None, w):
@@ -92,7 +92,7 @@ def test_scatter_segment_method_matches_window(d, g):
 
 def test_scatter_duplicate_cell_collisions():
     """Hundreds of points stacked into a handful of cells: the sorted
-    segment-reduce must sum every colliding corner (the regime the old
+    deposits must sum every colliding corner (the regime the old
     serial scatter handled by construction, and a vectorized deposit can
     silently drop)."""
     d, g, n = 3, 24, 500
@@ -123,32 +123,35 @@ def _stacked_points(d: int, g: int, n: int = 1000):
     return lo + (cells[pick] + jit_) * spacing, lo, spacing
 
 
-def _argsort_stream(rows, cw, blast, flast):
-    """Reference prep: argsort each chunk by row, gather every array."""
-    order = jnp.argsort(rows, axis=1, stable=True)
-    take = lambda a: jnp.take_along_axis(a, order, axis=1)  # noqa: E731
-    rows_s = take(rows)
-    segend = np.ones(rows_s.shape, np.int32)
-    segend[:, :-1] = np.asarray(rows_s[:, 1:] != rows_s[:, :-1])
-    return rows_s, take(cw), take(blast), take(flast), jnp.asarray(segend)
+def _argsort_stream(stream):
+    """Reference prep: argsort the cells, gather every array."""
+    order = jnp.argsort(stream[0], stable=True)
+    return tuple(a[order] for a in stream)
 
 
 @pytest.mark.parametrize("d,g", [(1, 64), (2, 48), (3, 24)])
 def test_sorted_corner_stream_matches_argsort_gathers(d, g):
     """The keyed sort's stream is bitwise the argsort-and-gather stream:
-    rows, weights, last-axis lanes and fractions, and segment ends."""
+    cells, fractions and weights; the chunked layout pads the ragged tail
+    with zero-weight points on the last real row."""
     x, lo, spacing = _stacked_points(d, g)
-    chunks = kb_ops.corner_chunks(x, lo, spacing, g, bm=64)
-    assert chunks[0].shape == (16, 64 * 2 ** (d - 1))
-    assert np.asarray(chunks[1][-1, -24 * 2 ** (d - 1):] == 0).all()
-    got = kb_ops.sort_chunks(*chunks)
-    want = _argsort_stream(*chunks)
-    for name, a, b in zip(("rows", "cw", "blast", "flast", "segend"),
-                          got, want):
-        assert a.dtype == b.dtype, name
+    w = jax.random.uniform(jax.random.PRNGKey(60 + d), (x.shape[0],)) + 0.5
+    stream = kb_ops.point_stream(x, lo, spacing, g, weights=w)
+    assert len(stream) == 2 + d
+    got = kb_ops.sort_stream(stream)
+    want = _argsort_stream(stream)
+    for k, (a, b) in enumerate(zip(got, want)):
+        assert a.dtype == b.dtype, k
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=name)
-    assert int(want[4].sum()) < want[4].size // 4   # long segments
+                                      err_msg=str(k))
+    first, last, rb, fw = kb_ops.chunk_stream(got, d, g, bm=64)
+    assert rb.shape == (16, 2, 64) and fw.shape == (16, 1 + 2 ** (d - 1), 64)
+    rows = np.asarray(rb[:, 0]).ravel()
+    assert (np.diff(rows) >= 0).all()            # sorted across chunks
+    assert rows[-24] == rows[-1] == int(got[0][-1]) // g
+    assert np.asarray(fw[-1, :, -24:] == 0).all()
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(rb[:, 0, 0]))
+    np.testing.assert_array_equal(np.asarray(last), np.asarray(rb[:, 0, -1]))
 
 
 @pytest.mark.parametrize("accumulator", ["plain", "compensated"])
@@ -158,19 +161,92 @@ def test_deposit_grid_bitwise_equals_argsort_stream(d, g, accumulator):
     kernel deposits from the argsort-and-gather stream."""
     x, lo, spacing = _stacked_points(d, g)
     comp = accumulator == "compensated"
-    rows, cw, blast, flast, segend = _argsort_stream(
-        *kb_ops.corner_chunks(x, lo, spacing, g, bm=64))
-    flat = lambda a: a.reshape(-1, 1)  # noqa: E731
+    stream = _argsort_stream(kb_ops.point_stream(x, lo, spacing, g))
     out = kb_kernel.scatter_sorted(
-        flat(rows), flat(cw), flat(blast), flat(flast), flat(segend),
-        rows_dim=g ** (d - 1), lanes_dim=g, kc=rows.shape[1],
-        compensated=comp, interpret=True)
+        *kb_ops.chunk_stream(stream, d, g, bm=64), dim=d, grid_size=g,
+        lanes_dim=g, compensated=comp, interpret=True)
     want = (out[0] + out[1]) if comp else out
-    want = np.asarray(want).reshape((g,) * d)
+    want = np.asarray(want)[:g ** (d - 1)].reshape((g,) * d)
     got = kb_ops.binned_scatter(x, lo, spacing, g, bm=64, interpret=True,
                                 accumulator=accumulator)
     np.testing.assert_array_equal(np.asarray(got), want)
     assert float(jnp.sum(got)) == pytest.approx(x.shape[0], rel=1e-5)
+
+
+# Hand-made streams for the one-hot window reduction, on a unit lattice
+# (lo 0, spacing 1: a point's base cell is its integer part).  P = 64 points
+# a chunk, W = 128 rows a window (8 at d = 1, where the lattice is one row).
+_LATTICE = {1: 64, 2: 300, 3: 24}        # R = 1, 300, 576 rows
+
+
+def _leading(r0: int, d: int, g: int) -> list[int]:
+    return [(r0 // g ** (d - 2 - k)) % g for k in range(d - 1)]
+
+
+def _hand_stream(kind: str, d: int):
+    """(base rows, hand-counted windows) of a hand-made stream.
+
+    dense: 200 points on one base row (4 chunks, the last ragged): one
+    window per plane and chunk.  spread: every 64-point chunk puts half its
+    points on row r and half on r + 130, so each plane of each chunk spans
+    two windows.  distinct: 256 points on 256 distinct rows (at d = 1, the
+    lattice's one row), windows counted in plain Python over the chunks.
+    """
+    g = _LATTICE[d]
+    planes = 2 ** (d - 1)
+    if d == 1:
+        n = 200 if kind == "dense" else 256
+        return [0] * n, -(-n // 64)
+    if kind == "dense":
+        return [127] * 200, 4 * planes
+    if kind == "spread":
+        starts = (0, 140) if d == 2 else (0, 140, 280, 420)
+        rows = [r + 130 * (k >= 32) for r in starts for k in range(64)]
+        return rows, 2 * planes * len(starts)
+    valid = [r for r in range(g ** (d - 1))
+             if all(c <= g - 2 for c in _leading(r, d, g))]
+    rows = valid[::2][:256] if d == 3 else valid[:256]
+    offsets = kb_kernel.plane_offsets(d, g)
+    count = 0
+    for c in range(0, len(rows), 64):
+        chunk = rows[c:c + 64]
+        for off in offsets:
+            count += (chunk[-1] + off) // 128 - (chunk[0] + off) // 128 + 1
+    return rows, count
+
+
+@pytest.mark.parametrize("kind", ["dense", "spread", "distinct"])
+@pytest.mark.parametrize("accumulator", ["plain", "compensated"])
+@pytest.mark.parametrize("d", [1, 2, 3])
+def test_deposit_matches_oracle_on_hand_made_streams(d, accumulator, kind):
+    """The one-hot window deposit matches the corner-loop oracle at rtol
+    1e-5 on a dense, a window-spanning and a distinct-row stream; the
+    window counts that bound the kernel's loops match a hand count and
+    stay within 2^(d-1) (n_chunks + Rp / W)."""
+    g = _LATTICE[d]
+    rows, hand = _hand_stream(kind, d)
+    n = len(rows)
+    key = jax.random.PRNGKey(70 + d)
+    last = jax.random.randint(key, (n,), 0, g - 1)
+    lead = jnp.asarray([_leading(r, d, g) for r in rows],
+                       jnp.int32).reshape(n, d - 1)
+    base = jnp.concatenate([lead, last[:, None]], axis=1)
+    frac = jax.random.uniform(jax.random.PRNGKey(80 + d), (n, d),
+                              minval=0.05, maxval=0.95)
+    x = base.astype(jnp.float32) + frac
+    lo, spacing = jnp.zeros((d,)), jnp.ones((d,))
+    want = kb_ref.binned_grid(x, lo, spacing, g)
+    got = kb_ops.binned_scatter(x, lo, spacing, g, bm=64, interpret=True,
+                                accumulator=accumulator)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+    first, last_row, _, _ = kb_ops.chunk_stream(
+        kb_ops.sort_stream(kb_ops.point_stream(x, lo, spacing, g)), d, g,
+        bm=64)
+    counts = np.asarray(kb_ops.window_counts(first, last_row, d, g))
+    assert int(counts.sum()) == hand, (counts, hand)
+    win = kb_kernel.window_rows(g ** (d - 1))
+    rows_pad = -(-g ** (d - 1) // win) * win
+    assert counts.sum() <= 2 ** (d - 1) * (len(counts) + rows_pad // win)
 
 
 def test_scatter_pallas_compensated_state_and_parity():
@@ -193,7 +269,7 @@ def test_scatter_pallas_compensated_state_and_parity():
 
 def test_dispatch_compensated_deposit_stays_on_pallas(monkeypatch):
     """`dispatch.binned_scatter(backend="pallas", accumulator="compensated")`
-    must run the Pallas segment-reduce kernel — the historical silent
+    must run the Pallas deposit kernel — the historical silent
     reroute to the XLA scatter is gone."""
     from repro.core import kde as core_kde
 

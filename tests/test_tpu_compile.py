@@ -67,29 +67,45 @@ def test_gram_kernel_compiles(one_chip, precision, compensated):
     assert _custom_calls(low) >= 1
 
 
+@pytest.fixture(scope="module")
+def deposit_hlo(one_chip):
+    """Compiled text of the Pallas deposit, one compile per shape: the
+    global keyed sort takes most of a deposit's compile time."""
+    cache = {}
+
+    def compiled(d, grid, compensated, rows=ROWS):
+        key = (d, grid, compensated, rows)
+        if key not in cache:
+            acc = "compensated" if compensated else "plain"
+            cache[key] = jax.jit(lambda p, lo, sp: kb_ops.binned_scatter(
+                p, lo, sp, grid, interpret=False, accumulator=acc)).lower(
+                    _spec((rows, d), one_chip), _spec((d,), one_chip),
+                    _spec((d,), one_chip)).compile().as_text()
+        return cache[key]
+    return compiled
+
+
 @pytest.mark.parametrize("compensated", [False, True])
 @pytest.mark.parametrize("d,grid", [(1, 1024), (2, 512), (3, 96)])
-def test_deposit_kernel_compiles(one_chip, d, grid, compensated):
-    """CIC segment-reduce deposit at the production grids (1024, 512^2,
+def test_deposit_kernel_compiles(deposit_hlo, d, grid, compensated):
+    """CIC one-hot window deposit at the production grids (1024, 512^2,
     96^3), its (hi, lo) pair when compensated."""
-    acc = "compensated" if compensated else "plain"
-    low = jax.jit(lambda p, lo, sp: kb_ops.binned_scatter(
-        p, lo, sp, grid, interpret=False, accumulator=acc)).lower(
-            _spec((ROWS, d), one_chip), _spec((d,), one_chip),
-            _spec((d,), one_chip))
-    assert _custom_calls(low) >= 1
+    assert deposit_hlo(d, grid, compensated).count("tpu_custom_call") >= 1
+
+
+def test_deposit_kernel_compiles_at_cell_rows(deposit_hlo):
+    """The deposit at the benchmark cell's 1,000,000 rows on the 96^3
+    lattice: 3,907 chunks of 256 points, their row bounds in SMEM."""
+    hlo = deposit_hlo(3, 96, False, rows=1_000_000)
+    assert hlo.count("tpu_custom_call") >= 1
 
 
 @pytest.mark.parametrize("d,grid", [(1, 1024), (2, 512), (3, 96)])
-def test_deposit_prep_sorts_once_without_gathers(one_chip, d, grid):
-    """At the production grids the deposit's corner stream is ordered by
+def test_deposit_prep_sorts_once_without_gathers(deposit_hlo, d, grid):
+    """At the production grids the deposit's point stream is ordered by
     one keyed sort that carries its payload: exactly one `sort` in the
     compiled module, and no gather applying the order afterwards."""
-    low = jax.jit(lambda p, lo, sp: kb_ops.binned_scatter(
-        p, lo, sp, grid, interpret=False)).lower(
-            _spec((ROWS, d), one_chip), _spec((d,), one_chip),
-            _spec((d,), one_chip))
-    hlo = low.compile().as_text()
+    hlo = deposit_hlo(d, grid, False)
     assert len(re.findall(r"\bsort\(", hlo)) == 1
     assert not re.findall(r"\bgather\(", hlo)
 
