@@ -586,8 +586,8 @@ def online_bench(n: int = 262_144, seed: int = 0) -> list[dict]:
 def calibrate_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
     """Sweep-vs-naive economics of the CalibrateStage fold at one n.
 
-    Times the shared-Gram multi-lam path (`nystrom.fit_streaming_multi`:
-    one row-stream accumulation + L whitened solves + one multi-beta
+    Times the shared-Gram multi-lam path (`nystrom.fit_streaming` over the
+    grid: one row-stream accumulation + L whitened solves + one multi-beta
     predict) against the naive per-lam refit loop (L independent
     `fit_streaming` + `predict_streaming` calls) on the SAME landmark set
     and lam grid, then runs the full `SAKRRPipeline.calibrate` fold and
@@ -612,19 +612,20 @@ def calibrate_bench(n: int = 16_384, seed: int = 0) -> list[dict]:
         key, rls.uniform(n - n_val).probs, m)
 
     # warm every jit cache both timed regions hit (same shapes)
-    warm = nystrom.fit_streaming_multi(kern, x_tr, y_tr, lam_grid, idx,
-                                       tile=cfg.tile)
-    jax.block_until_ready(nystrom.predict_streaming_multi(
-        kern, warm, x_val, tile=cfg.tile))
+    warm = nystrom.fit_streaming(kern, x_tr, y_tr, lam_grid, idx,
+                                 tile=cfg.tile)
+    jax.block_until_ready(nystrom.predict_streaming(
+        kern, warm._replace(beta=warm.beta.T), x_val, tile=cfg.tile))
     w1 = nystrom.fit_streaming(kern, x_tr, y_tr, lam_grid[0], idx,
                                tile=cfg.tile)
     jax.block_until_ready(nystrom.predict_streaming(kern, w1, x_val,
                                                     tile=cfg.tile))
 
     t0 = time.perf_counter()
-    fits = nystrom.fit_streaming_multi(kern, x_tr, y_tr, lam_grid, idx,
-                                       tile=cfg.tile)
-    preds = nystrom.predict_streaming_multi(kern, fits, x_val, tile=cfg.tile)
+    grid = nystrom.fit_streaming(kern, x_tr, y_tr, lam_grid, idx,
+                                 tile=cfg.tile)
+    preds = nystrom.predict_streaming(kern, grid._replace(beta=grid.beta.T),
+                                      x_val, tile=cfg.tile)
     jax.block_until_ready(preds)
     sweep_s = time.perf_counter() - t0
 
@@ -673,7 +674,9 @@ def mesh2d_bench(n: int = 8_192) -> list[dict]:
     """The calibrate sweep's per-h KDE and multi-lam solve under a (2, 2)
     (data, model) mesh vs the 1D replicated baseline — wall-clock for both
     plus the per-h/per-lam bit-equality flags (the 2D path must match the
-    1D data-mesh path with the same data-shard count exactly).
+    1D data-mesh path with the same data-shard count exactly).  The KDE
+    splits its bandwidths over the model axis; the lam grid's solve runs
+    the eager per-lam chain on every chip under either mesh.
 
     Runs in THIS process on four devices: four chips, or four forced host
     devices set before JAX starts
@@ -712,7 +715,7 @@ def mesh2d_bench(n: int = 8_192) -> list[dict]:
 
     def solve_sweep():
         return jax.block_until_ready(
-            nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid))
+            nystrom.solve_normal_eq(g, rhs, k_mm, n, lam_grid))
 
     def timed(mesh):
         with shd.activate(mesh):

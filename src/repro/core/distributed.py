@@ -1,90 +1,23 @@
-"""Distributed (pjit) SA-leverage + Nyström KRR — the paper's pipeline on the
-production mesh.
+"""The binned KDE under a mesh: each chip deposits its own rows into the
+lattice, one psum joins the lattices, and the read-back smooths and gathers
+per bandwidth at the local rows.
 
-This is the deployment form of the paper's contribution: at n ~ 10^7-10^8 a
-single host can neither hold the (n, d) design nor the (n, m) cross-kernel
-matrix, so the pipeline shards the SAMPLE dimension over the whole mesh
-(every chip owns n/chips rows) and keeps landmarks replicated:
-
-  1. KDE        p_i = mean_j k_h(x_i - x_j)     — sharded queries against
-                replicated source batches (TPU: the Pallas `kde` kernel per
-                shard; here the fused-XLA oracle) -> no n x n materialisation;
-  2. SA map     q_i ∝ p_i^{d/(2α)-1}            — elementwise (Eq. 6 closed
-                form), embarrassingly parallel; the normaliser is one psum;
-  3. Nyström    K_nm^T K_nm and K_nm^T y reduce over the sharded n axis
-                (GSPMD inserts the all-reduce), the m x m solve is replicated;
-  4. predict    sharded rows x replicated beta.
-
-Everything is jit-able end to end; `lower_pipeline` is the dry-run/roofline
-entry (abstract inputs, both production meshes), and tests check the sharded
-path equals the single-device reference bit-for-bit (up to reduction order).
-
-For the KDE source set we follow the paper's subsampled-KDE argument (App. E:
-o(1) relative KDE error suffices): density is estimated against a uniform
-m_kde-subsample of the data (m_kde ~ sqrt(n) keeps the KDE term o(n) compute
-per chip while its error stays within the Thm-5 slack).
+This is the density stage of the pipeline (`repro.pipeline.stages`
+DensityStage and CalibrateStage) when a mesh is active; the normal
+equations shard through `repro.core.streaming` (`nystrom.normal_eq_state`).
 """
 
 from __future__ import annotations
 
 import functools
-import math
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import spans
-from repro.core import kernels as K
-from repro.core import leverage, streaming
-from repro.distributed.sharding import constrain
+from repro.core import streaming
 
 Array = jax.Array
-
-
-class SAPipelineOut(NamedTuple):
-    probs: Array        # (n,) SA sampling distribution
-    d_stat: Array       # scalar
-    beta: Array         # (m,) Nyström coefficients
-    fitted: Array       # (n,) in-sample predictions
-
-
-def _sq_dists(x: Array, y: Array) -> Array:
-    x2 = jnp.sum(x * x, axis=-1)[:, None]
-    y2 = jnp.sum(y * y, axis=-1)[None, :]
-    return jnp.maximum(x2 + y2 - 2.0 * (x @ y.T), 0.0)
-
-
-def _sq_dists_augmented(x: Array, y: Array) -> Array:
-    """||x-y||^2 as ONE (d+2)-wide GEMM (§Perf cell C iter 3).
-
-    [ -2x | ||x||^2 | 1 ] . [ y | 1 | ||y||^2 ]^T = ||x||^2 + ||y||^2 - 2x.y
-    — the broadcast+add assembly of the standard expansion disappears from
-    the HLO (its bytes dominated the pipeline after iter 1).  +2 columns of
-    GEMM flops, exact same math up to addition order.
-    """
-    n, d = x.shape
-    ones_x = jnp.ones((n, 1), x.dtype)
-    x_aug = jnp.concatenate([-2.0 * x, jnp.sum(x * x, -1, keepdims=True),
-                             ones_x], axis=1)
-    ones_y = jnp.ones((y.shape[0], 1), y.dtype)
-    y_aug = jnp.concatenate([y, ones_y, jnp.sum(y * y, -1, keepdims=True)],
-                            axis=1)
-    return jnp.maximum(x_aug @ y_aug.T, 0.0)
-
-
-def kde_sharded(x: Array, kde_sample: Array, h: float) -> Array:
-    """p_hat at every x_i against the (replicated) KDE subsample.
-
-    x is row-sharded over the mesh ('batch' rule); the (n_loc, m_kde) weight
-    tile lives per chip only.
-    """
-    m, d = kde_sample.shape
-    x = constrain(x, ("batch", None))
-    sq = _sq_dists(x, kde_sample)
-    w = jnp.exp(-sq / (2.0 * h * h))
-    norm = 1.0 / (m * (2.0 * math.pi * h * h) ** (d / 2.0))
-    return constrain(norm * jnp.sum(w, axis=1), ("batch",))
 
 
 def kde_binned_sharded(x: Array, h: float, *, grid_size: int = 96,
@@ -255,107 +188,3 @@ def grid_geometry(x: Array, h: float | Array | None = None
     h = jnp.asarray(h, x.dtype)
     lo, hi = core_kde.binned_bounds(x, x, h)
     return h, lo, hi
-
-
-def sa_nystrom_pipeline(
-    x: Array,              # (n, d)    row-sharded design
-    y: Array,              # (n,)      row-sharded responses
-    kde_sample: Array,     # (m_kde, d) replicated KDE source subsample
-    landmark_idx: Array,   # (m,) int  landmark rows (sampled on host from q)
-    *,
-    kernel: K.Matern,
-    lam: float,
-    kde_h: float,
-    kde_method: str = "direct",     # direct | binned  (§Perf cell C, iter 1)
-    knm_dtype=jnp.float32,          # bf16 halves k_nm traffic (iter 2)
-) -> SAPipelineOut:
-    n, d = x.shape
-    # 1-2) density -> SA leverage (Eq. 6 closed form) -> sampling weights
-    if kde_method == "binned":
-        p = kde_binned_sharded(x, kde_h)
-    else:
-        p = kde_sharded(x, kde_sample, kde_h)
-    raw = leverage.matern_closed_form(p, lam, kernel, d)
-    raw = jnp.minimum(raw, float(n))
-    total = jnp.sum(raw)                       # psum over the sharded axis
-    probs = raw / total
-
-    # 3) Nyström normal equations; n-axis reduction is the big all-reduce
-    xm = x[landmark_idx]                       # gather -> replicated (m, d)
-    sq = _sq_dists_augmented(x.astype(knm_dtype), xm.astype(knm_dtype))
-    k_nm = kernel.from_distance(jnp.sqrt(sq)).astype(knm_dtype)
-    k_nm = constrain(k_nm, ("batch", None))    # (n_loc-sharded, m)
-    k_mm = kernel(xm, xm)
-    m = xm.shape[0]
-
-    # Fused dense normal equations through the streaming engine: a one-step
-    # (tile=None) `tile_reduce` whose emit is the pair of fp32-accumulated
-    # MXU dot_generals the historical inline path ran — the scan body
-    # compiles to the same fused computation (zero-init add is exact), and
-    # GSPMD still turns the n-axis contraction into the one big all-reduce.
-    def emit(k_tile, y_tile):
-        g = jax.lax.dot_general(k_tile, k_tile, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        r = jax.lax.dot_general(k_tile, y_tile, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        return g, r
-
-    g, rhs = streaming.tile_reduce(
-        emit, k_nm, (y.astype(knm_dtype),), tile=None,
-        init=(jnp.zeros((m, m), jnp.float32), jnp.zeros((m,), jnp.float32)),
-        pad="zero")
-    lhs = g + n * lam * k_mm
-    scale = jnp.trace(lhs) / m
-    lhs = lhs + (1e-6 * scale) * jnp.eye(m, dtype=lhs.dtype)
-    beta = jnp.linalg.solve(lhs, rhs)          # replicated small solve
-
-    # 4) in-sample predictions, sharded rows
-    fitted = constrain((k_nm @ beta.astype(knm_dtype)).astype(jnp.float32),
-                       ("batch",))
-    return SAPipelineOut(probs=probs, d_stat=total / n, beta=beta,
-                         fitted=fitted)
-
-
-def make_pipeline_fn(kernel: K.Matern, lam: float, kde_h: float,
-                     kde_method: str = "direct", knm_dtype=jnp.float32):
-    return functools.partial(sa_nystrom_pipeline, kernel=kernel, lam=lam,
-                             kde_h=kde_h, kde_method=kde_method,
-                             knm_dtype=knm_dtype)
-
-
-def abstract_inputs(n: int, d: int, m_kde: int, m: int, dtype=jnp.float32):
-    """ShapeDtypeStructs for lower(): sharded x/y, replicated sample/idx."""
-    from repro.distributed import sharding as shd
-    act = shd.active()
-
-    def sds(shape, dt, axes):
-        if act is None:
-            return jax.ShapeDtypeStruct(shape, dt)
-        return jax.ShapeDtypeStruct(shape, dt,
-                                    sharding=act.sharding(axes, shape))
-
-    return (
-        sds((n, d), dtype, ("batch", None)),
-        sds((n,), dtype, ("batch",)),
-        sds((m_kde, d), dtype, (None, None)),
-        sds((m,), jnp.int32, (None,)),
-    )
-
-
-def lower_pipeline(mesh, *, n: int, d: int = 3, nu: float = 1.5,
-                   lam: float | None = None, m_kde: int | None = None,
-                   m: int | None = None, kde_method: str = "direct",
-                   knm_dtype=jnp.float32):
-    """Dry-run entry: lower + compile the full pipeline on `mesh`."""
-    from repro.distributed import sharding as shd
-    lam = lam if lam is not None else 0.075 * n ** (-2.0 / 3.0)
-    m = m if m is not None else int(5 * n ** (1.0 / 3.0))
-    m_kde = m_kde if m_kde is not None else max(1024, int(n ** 0.5))
-    kde_h = 0.15 * n ** (-1.0 / 7.0)
-    kernel = K.Matern(nu=nu)
-    fn = make_pipeline_fn(kernel, lam, kde_h, kde_method, knm_dtype)
-    rules = {"batch": ("pod", "data", "model")}  # pure row sharding: all chips
-    with shd.activate(mesh, rules):
-        args = abstract_inputs(n, d, m_kde, m)
-        lowered = jax.jit(fn).lower(*args)
-        return lowered, lowered.compile()

@@ -17,13 +17,20 @@ Two solve paths:
 
   * ``fit_from_landmarks`` / ``fit`` — dense: materializes K_nm.  Simple and
     fine up to n ~ 1e5; it is also the parity oracle for the streaming path.
-  * ``fit_streaming`` — accumulates G = K_nm^T K_nm and rhs = K_nm^T y over
-    row tiles (lax.scan on the XLA backend, the fused Pallas ``gram`` kernel
-    on TPU — see `repro.kernels.dispatch`), so peak memory is O(tile * m)
-    regardless of n.  Under an active mesh (`repro.distributed.sharding`)
-    the row stream is sharded over the "rows" logical axis (mesh axis
-    "data" by default) and G/rhs are psum-reduced — a transparent no-op on
-    a single device.
+  * ``fit_streaming`` — one pass (``normal_eq_state``) accumulates
+    G = K_nm^T K_nm and rhs = K_nm^T y over row tiles (lax.scan on the XLA
+    backend, the fused Pallas ``gram`` kernel on TPU — see
+    `repro.kernels.dispatch`), so peak memory is O(tile * m) regardless of
+    n, and one solve (``solve_from_state``) follows.  Under an active mesh
+    (`repro.distributed.sharding`) the row stream is sharded over the
+    "rows" logical axis (mesh axis "data" by default) and G/rhs are
+    psum-reduced — a transparent no-op on a single device.
+
+The same state solves for one lam or a lam grid (`solve_normal_eq`: one
+eigh of K_mm, one whitened tail per lam), carries score targets as extra
+rhs columns, and continues under `normal_eq_absorb`; `fit_streaming_batched`
+vmaps the same solve over a model batch.  Splits over the "models" axis
+(a lam grid, a model batch) run through `streaming.mesh_map`.
 """
 
 from __future__ import annotations
@@ -94,43 +101,17 @@ def weighted_normal_eq(g: Array, rhs: Array, k_mm: Array,
 
 
 def _whitened_solve(g: Array, rhs: Array, evals: Array, evecs: Array,
-                    g_max: Array, n: int, lam: float, jitter: float,
-                    eps_scale: float = 1.0) -> Array:
-    """The per-lam tail of `solve_normal_eq`: truncate + whiten + solve.
+                    g_max: Array, nlam: Any, *, jitter: float,
+                    eps: float) -> Array:
+    """The per-lam tail of the solve: truncate + whiten + solve.
 
     Takes the lam-INDEPENDENT eigendecomposition of K_mm (and the trace
-    upper bound on lambda_max(G)) as inputs, so a lam sweep pays the O(m^3)
-    eigh once and only re-runs this O(m^3-but-tiny) tail per candidate —
-    the op sequence per lam is identical to the single-lam solve, so the
-    sweep is bit-equal to per-lam solves (locked in tests/test_calibrate.py).
-    `eps_scale` shrinks the dtype-noise-floor term for Grams accumulated
-    with less noise than a plain fp32 running sum (streaming.EPS_SCALE).
-    """
-    m = evals.shape[0]
-    eps = float(jnp.finfo(g.dtype).eps) * eps_scale
-    tau = jnp.maximum(jitter * evals[-1], eps * g_max / (n * lam))
-    inv_sqrt = jnp.where(evals > tau, 1.0 / jnp.sqrt(jnp.maximum(evals, tau)),
-                         0.0)
-    w = evecs * inv_sqrt[None, :]                         # (m, m) whitener
-    a = w.T @ g @ w
-    b = w.T @ rhs
-    gamma = jnp.linalg.solve(a + n * lam * jnp.eye(m, dtype=a.dtype), b)
-    return w @ gamma
-
-
-def _whitened_solve_nlam(g: Array, rhs: Array, evals: Array, evecs: Array,
-                         g_max: Array, nlam: Array, jitter: float,
-                         eps: float) -> Array:
-    """`_whitened_solve` with the n*lam product passed as a DEVICE scalar.
-
-    The python-float path computes n*lam on the host and lets weak-type
-    promotion round it to the array dtype at the two use sites; passing
-    ``nlam = jnp.asarray(n * lam, g.dtype)`` reproduces exactly that
-    rounding, so this variant is bit-equal to `_whitened_solve` while being
-    traceable over lam — the form the model-sharded lam sweep
-    (`solve_normal_eq_multi`) and the vmapped many-model solve
-    (`solve_normal_eq_batched`) need.  ``eps`` is the already-scaled
-    noise-floor factor (float(finfo.eps) * eps_scale).
+    upper bound on lambda_max(G)) as inputs, so a lam grid pays the O(m^3)
+    eigh once and only re-runs this tail per candidate.  ``nlam`` is n*lam
+    computed in host float64 and rounded once to G's dtype: a python float
+    rounds where it meets G, a device scalar (a model batch's) was rounded
+    on the host, and both give the same bits.  ``eps`` is the
+    noise-floor factor, eps(dtype) times the solve's ``eps_scale``.
     """
     m = evals.shape[0]
     tau = jnp.maximum(jitter * evals[-1], eps * g_max / nlam)
@@ -143,8 +124,33 @@ def _whitened_solve_nlam(g: Array, rhs: Array, evals: Array, evecs: Array,
     return w @ gamma
 
 
-def solve_normal_eq(g: Array, rhs: Array, k_mm: Array, n: int, lam: float,
-                    jitter: float = 1e-6, eps_scale: float = 1.0) -> Array:
+def _solve(nlam: Any, g: Array, rhs: Array, k_mm: Array, *, jitter: float,
+           eps: float) -> Array:
+    """One eigh of K_mm and one trace of G, then the whitened tail for each
+    n*lam: a scalar ``nlam`` gives (m,), a sequence of L gives (L, m), each
+    row the op sequence of the scalar solve.  `fit_streaming_batched`
+    vmaps it over a model batch."""
+    evals, evecs = jnp.linalg.eigh(k_mm)
+    # trace >= lambda_max for PSD G, and is tight here (G's spectrum is
+    # dominated by the near-constant kernel component) — O(m) vs an O(m^3)
+    # eigendecomposition for a quantity that only needs an upper bound.
+    g_max = jnp.trace(g)
+    tail = functools.partial(_whitened_solve, g, rhs, evals, evecs, g_max,
+                             jitter=jitter, eps=eps)
+    if np.ndim(nlam) == 0:
+        return tail(nlam)
+    return jnp.stack([tail(nl) for nl in nlam])
+
+
+def _solve_models(nlams: Array, gs: Array, rhss: Array, k_mms: Array, *,
+                  jitter: float, eps: float) -> Array:
+    """`_solve` vmapped over a leading model axis: (B, m) betas."""
+    return jax.vmap(functools.partial(_solve, jitter=jitter, eps=eps))(
+        nlams, gs, rhss, k_mms)
+
+
+def solve_normal_eq(g: Array, rhs: Array, k_mm: Array, n: float, lams: Any,
+                    *, jitter: float = 1e-6, eps_scale: float = 1.0) -> Array:
     """beta = (G + n lam K_mm)^{-1} rhs via spectrally-truncated whitening.
 
     The plain normal equations are numerically hopeless at scale: K_mm's
@@ -170,76 +176,16 @@ def solve_normal_eq(g: Array, rhs: Array, k_mm: Array, n: int, lam: float,
     two-float stream passes `streaming.EPS_SCALE["compensated"]` so the
     solve KEEPS the directions whose signal the better accumulation
     preserved (regression-tested in tests/test_streaming_engine.py).
+
+    ``lams`` is one lam, giving beta (m,), or a sequence of L, giving the
+    (L, m) stack: the eigh and the trace run once and only the tail runs
+    per lam, each as the same eager op chain as the solve at that lam
+    alone, so row i is bitwise the solve at ``lams[i]`` — with or without
+    a mesh, where every chip solves the whole grid.
     """
-    evals, evecs = jnp.linalg.eigh(k_mm)
-    # trace >= lambda_max for PSD G, and is tight here (G's spectrum is
-    # dominated by the near-constant kernel component) — O(m) vs an O(m^3)
-    # eigendecomposition for a quantity that only needs an upper bound.
-    g_max = jnp.trace(g)
-    return _whitened_solve(g, rhs, evals, evecs, g_max, n, lam, jitter,
-                           eps_scale)
-
-
-def solve_normal_eq_multi(g: Array, rhs: Array, k_mm: Array, n: int,
-                          lams: Sequence[float],
-                          jitter: float = 1e-6,
-                          eps_scale: float = 1.0) -> Array:
-    """`solve_normal_eq` over a lam grid, sharing the eigendecomposition.
-
-    The truncation cutoff tau depends on lam, so each candidate gets its own
-    whitener — but the K_mm eigh and the G trace are lam-independent and run
-    once.  Returns the (L, m) stack of betas, row i bit-equal to
-    `solve_normal_eq(g, rhs, k_mm, n, lams[i])` (same op sequence).
-
-    Under an active 2D (data, model) mesh whose "models" rule divides L
-    (`repro.distributed.sharding`), the per-lam tails SHARD across the
-    model axis: the eigendecomposition stays replicated, each chip column
-    solves its L / M slice of the grid, and the stack reassembles
-    model-sharded — bit-equal per row to the 1D-data-mesh sweep because
-    the n*lam products are pre-rounded host-side (`_whitened_solve_nlam`)
-    and the per-lam op chain does not depend on how many lams a chip
-    holds.  (Mesh execution compiles the tail, so mesh runs differ from
-    the eager no-mesh loop by FMA-fusion rounding only.)
-    """
-    from repro.distributed import sharding as shd
-
-    evals, evecs = jnp.linalg.eigh(k_mm)
-    g_max = jnp.trace(g)
-    lam_list = [float(lam) for lam in lams]
-    act = shd.active()
-    if act is not None:
-        from jax.sharding import PartitionSpec as P
-
-        # ANY active mesh routes through shard_map — a 1D data mesh runs
-        # the body replicated (model_axes None), so adding a model axis
-        # changes only how many lams each chip column holds, never the
-        # per-lam op chain: the 2D sweep is bit-equal per row to the 1D
-        # mesh sweep (locked in tests/test_mesh2d.py).  The NO-mesh path
-        # below keeps the historical eager loop (and its bit-locks against
-        # the single-lam solve, tests/test_calibrate.py).
-        model_axes = act.spec(("models",), (len(lam_list),))[0]
-        eps = float(jnp.finfo(g.dtype).eps) * eps_scale
-        # host-f64 n*lam products, rounded ONCE to the array dtype — the
-        # same values weak promotion produces inside `_whitened_solve`.
-        nlams = jnp.asarray([n * lam for lam in lam_list], g.dtype)
-
-        def body(nlams_loc, g_, rhs_, evals_, evecs_, g_max_):
-            return jnp.stack([
-                _whitened_solve_nlam(g_, rhs_, evals_, evecs_, g_max_,
-                                     nlams_loc[i], jitter, eps)
-                for i in range(nlams_loc.shape[0])])
-
-        rep = (g, rhs, evals, evecs, g_max)
-        return jax.shard_map(
-            body, mesh=act.mesh,
-            in_specs=(P(model_axes),) + tuple(
-                P(*([None] * a.ndim)) for a in rep),
-            out_specs=P(model_axes) if model_axes is not None else P())(
-            nlams, *rep)
-    return jnp.stack([
-        _whitened_solve(g, rhs, evals, evecs, g_max, n, lam, jitter,
-                        eps_scale)
-        for lam in lam_list])
+    eps = float(jnp.finfo(g.dtype).eps) * eps_scale
+    nlam = n * lams if np.ndim(lams) == 0 else [n * lam for lam in lams]
+    return _solve(nlam, g, rhs, k_mm, jitter=jitter, eps=eps)
 
 
 def fit_from_landmarks(
@@ -292,27 +238,12 @@ def fitted(kernel: Kernel, fit_: NystromFit, x_train: Array) -> Array:
 
 # ---------------------------------------------------------------- streaming --
 
-def _scan_steps(n: int, tile: int, x: Array,
-                backend: str | None = None) -> int:
-    """Accumulation steps the Gram stream ran PER CHIP — the budget
-    `eps_scale` may lower the compensated truncation floor by.  Under an
-    active mesh the stream is row-sharded, so each chip saw only
-    n / row_shard_count rows (a one-tile-per-chip stream has no cross-tile
-    error to compensate even when the global n spans several tiles).  The
-    fold granularity is backend-dependent: the XLA engine compensates
-    across `tile`-row scan steps, the Pallas gram kernel across its bm-row
-    (<= 256) VMEM tile folds — so the TPU path earns its lower floor even
-    when n fits one XLA-sized slab."""
-    from repro.kernels import dispatch
-    n_loc = max(1, n // streaming.row_shard_count(x.shape))
-    grain = 256 if dispatch.resolve(backend) == "pallas" else tile
-    return -(-n_loc // min(grain, n_loc))
-
-
-def _resolve_gram_exec(tile: int | None, precision: str | None, x: Array,
-                       xm: Array, backend: str | None, accumulator: str,
-                       num_models: int = 1) -> tuple[int | None, str]:
-    """Resolve the Gram stream's (tile, precision) execution pair.
+def _gram_exec(x: Array, xm: Array, tile: int | None, precision: str | None,
+               backend: str | None, accumulator: str,
+               num_models: int = 1) -> tuple[int | None, str, int]:
+    """The prologue every normal-equation pass shares: the stream's
+    resolved (tile, precision) pair and the accumulation steps it runs PER
+    CHIP.
 
     ``tile=None`` -> the autotuned XLA engine tile (`repro.tuning` via
     `dispatch.resolve_plan`); explicit tiles pass through untouched, and the
@@ -323,7 +254,15 @@ def _resolve_gram_exec(tile: int | None, precision: str | None, x: Array,
     its precision defaults to the historical "fp32" (bit parity with
     pre-precision code).  Resolution is per-chip: under an active mesh each
     device streams only n / row_shard_count rows, which is the stream the
-    tile must fit."""
+    tile must fit.
+
+    The steps are the budget `eps_scale` may lower the compensated
+    truncation floor by: a one-tile-per-chip stream has no cross-tile error
+    to compensate even when the global n spans several tiles.  The fold
+    granularity is backend-dependent: the XLA engine compensates across
+    `tile`-row scan steps, the Pallas gram kernel across its bm-row (<= 256)
+    VMEM tile folds — so the TPU path earns its lower floor even when n
+    fits one XLA-sized slab."""
     from repro.kernels import dispatch
     n_loc = max(1, x.shape[0] // streaming.row_shard_count(x.shape))
     if dispatch.resolve(backend) == "pallas":
@@ -332,15 +271,15 @@ def _resolve_gram_exec(tile: int | None, precision: str | None, x: Array,
                 "gram", n_loc, xm.shape[0], x.shape[1], dtype=x.dtype,
                 backend="pallas", accumulator=accumulator,
                 precision=None, num_models=num_models).precision
-        return tile, precision
+        return tile, precision, -(-n_loc // min(256, n_loc))
     if tile is None:
         plan = dispatch.resolve_plan("gram", n_loc, xm.shape[0], x.shape[1],
                                      dtype=x.dtype, backend="xla",
                                      accumulator=accumulator,
                                      precision=precision,
                                      num_models=num_models)
-        return plan.tile, (precision or plan.precision)
-    return tile, (precision or "fp32")
+        tile, precision = plan.tile, (precision or plan.precision)
+    return tile, (precision or "fp32"), -(-n_loc // min(tile, n_loc))
 
 
 def _eff_eps_scale(accumulator: str, steps: int, precision: str) -> float:
@@ -386,7 +325,7 @@ def _gram_normal_eq(kernel: Kernel, x: Array, y: Array, xm: Array, *,
                     interpret: bool | None, accumulator: str,
                     precision: str = "fp32",
                     finalize: bool = True) -> tuple[Array, Array]:
-    """The (G, rhs) accumulation behind `fit_streaming[_multi]`.
+    """The (G, rhs) accumulation behind `normal_eq_state`.
 
     When the tile came from the autotuner (`autotuned=True`, i.e. the caller
     passed ``tile=None``) and the call is a plain single-device XLA stream
@@ -451,8 +390,8 @@ def scan_normal_eq(kernel: Kernel, x: Array, xm: Array, w: Array,
     historical single dot_general; the bf16 modes are the XLA split-dot
     twin of the Pallas bf16 kernel (slow on CPU, parity-testable anywhere).
     """
-    tile, precision = _resolve_gram_exec(tile, precision, x, xm, "xla",
-                                         accumulator)
+    tile, precision, _ = _gram_exec(x, xm, tile, precision, "xla",
+                                    accumulator)
     m = xm.shape[0]
     acc = jnp.promote_types(x.dtype, jnp.float32)  # f64 under enable_x64
 
@@ -489,8 +428,8 @@ def streaming_normal_eq(kernel: Kernel, x: Array, y: Array, xm: Array,
     any tuning micro-benchmark runs outside the shard_map trace and every
     chip executes the same plan.
     """
-    tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
-                                         accumulator)
+    tile, precision, _ = _gram_exec(x, xm, tile, precision, backend,
+                                    accumulator)
     local = functools.partial(_gram_local, kernel=kernel, backend=backend,
                               tile=tile, interpret=interpret,
                               accumulator=accumulator, precision=precision)
@@ -522,11 +461,12 @@ class NormalEqState:
     Bundles the raw (G, rhs) strategy state (`accstate.AccState` — rows
     and per-chip scan steps ride along), the landmark set it was built
     against, and the cached O(m^2) K_mm, plus the static execution knobs
-    every later absorb must reuse.  Monoid ops: `normal_eq_init` /
-    `normal_eq_absorb` / `normal_eq_merge` / `normal_eq_decay` /
-    `solve_from_state`.  All array members are leaves, so the state
-    round-trips through `checkpoint.Manager` and psums unchanged; the
-    exec knobs are static aux data.
+    every later absorb must reuse.  Built by one pass (`normal_eq_state`)
+    or from the identity (`normal_eq_init`); monoid ops: `normal_eq_absorb`
+    / `normal_eq_merge` / `normal_eq_decay`; solved by `solve_from_state`.
+    All array members are leaves, so the state round-trips through
+    `checkpoint.Manager` and psums unchanged; the exec knobs are static
+    aux data.
     """
 
     acc: accstate.AccState      # value = raw ((m,m), (m,[k])) strategy state
@@ -556,7 +496,6 @@ class NormalEqState:
 
 def normal_eq_init(kernel: Kernel, landmarks: Array,
                    landmark_idx: Array | None = None, *,
-                   rhs_cols: int | None = None,
                    dtype=jnp.float32,
                    tile: int | None = None,
                    backend: str | None = None,
@@ -565,8 +504,6 @@ def normal_eq_init(kernel: Kernel, landmarks: Array,
                    precision: str | None = "fp32") -> NormalEqState:
     """The identity state for a fixed landmark set (zero G/rhs, zero rows).
 
-    ``rhs_cols=None`` sizes rhs as (m,) — the single-response fit;
-    an integer widens it to (m, rhs_cols) (the scored-moment stream).
     ``tile=None`` defers tile resolution to each absorb (resolved per
     chunk shape); pass the resolved integer to pin one plan for the
     stream's lifetime — what `fit_streaming(..., return_state=True)` does.
@@ -574,8 +511,7 @@ def normal_eq_init(kernel: Kernel, landmarks: Array,
     _require_sentinel_safe(kernel)
     m = landmarks.shape[0]
     acc_dtype = jnp.promote_types(dtype, jnp.float32)
-    rhs_shape = (m,) if rhs_cols is None else (m, int(rhs_cols))
-    zeros = (jnp.zeros((m, m), acc_dtype), jnp.zeros(rhs_shape, acc_dtype))
+    zeros = (jnp.zeros((m, m), acc_dtype), jnp.zeros((m,), acc_dtype))
     if landmark_idx is None:
         landmark_idx = jnp.arange(m)
     return NormalEqState(
@@ -602,8 +538,8 @@ def normal_eq_absorb(kernel: Kernel, state: NormalEqState, x: Array,
 
     _require_sentinel_safe(kernel)
     xm = state.landmarks
-    tile, precision = _resolve_gram_exec(state.tile, state.precision, x, xm,
-                                         state.backend, state.accumulator)
+    tile, precision, steps = _gram_exec(x, xm, state.tile, state.precision,
+                                        state.backend, state.accumulator)
     n = x.shape[0]
     single = (streaming.row_shard_count(x.shape) == 1
               and dispatch.resolve(state.backend) == "xla")
@@ -621,7 +557,7 @@ def normal_eq_absorb(kernel: Kernel, state: NormalEqState, x: Array,
         value = streaming.get(state.accumulator).merge(state.acc.value, fresh)
     acc = accstate.AccState(
         value=value, rows=state.acc.rows + n,
-        steps=state.acc.steps + _scan_steps(n, tile, x, state.backend),
+        steps=state.acc.steps + steps,
         spec=state.acc.spec)
     return dataclasses.replace(state, acc=acc)
 
@@ -638,16 +574,20 @@ def normal_eq_decay(state: NormalEqState, gamma: float) -> NormalEqState:
     return dataclasses.replace(state, acc=accstate.decay(state.acc, gamma))
 
 
-def solve_from_state(state: NormalEqState, lam: float, *,
+def solve_from_state(state: NormalEqState, lams: float | Sequence[float], *,
                      jitter: float = 1e-6,
                      weights: Array | None = None) -> NystromFit:
-    """Finalize the accumulated (G, rhs) and re-run the O(m^3) solve.
+    """Finalize the accumulated (G, rhs) and run the O(m^3) solve.
 
     This is the ONLY per-update cost a `partial_fit` pays beyond absorbing
     the new tiles: n is the state's effective (possibly decayed) row count
     and the truncation floor uses the absorbed per-chip step count, so a
     state built by one uninterrupted fold solves bit-equal to the one-shot
-    `fit_streaming`.
+    `fit_streaming`.  ``lams`` is one lam, or a sequence whose fit holds
+    the (L, m) stack of betas (`solve_normal_eq`) and the tuple of lams.
+    A widened state solves against its first rhs column.  ``weights``
+    applies the without-replacement importance correction as an O(m^2)
+    column rescaling (`weighted_normal_eq`).
     """
     g, rhs = accstate.finalize(state.acc)
     if rhs.ndim != 1:
@@ -657,20 +597,79 @@ def solve_from_state(state: NormalEqState, lam: float, *,
     k_mm = state.k_mm
     if weights is not None:
         g, rhs, k_mm = weighted_normal_eq(g, rhs, k_mm, weights)
-    beta = solve_normal_eq(g, rhs, k_mm, n, lam, jitter=jitter,
+    beta = solve_normal_eq(g, rhs, k_mm, n, lams, jitter=jitter,
                            eps_scale=_eff_eps_scale(
                                state.accumulator, steps, state.precision))
     if weights is not None:
         beta = weights.astype(beta.dtype) * beta
     return NystromFit(beta=beta, landmarks=state.landmarks,
-                      landmark_idx=state.landmark_idx, lam=lam)
+                      landmark_idx=state.landmark_idx,
+                      lam=lams if np.ndim(lams) == 0 else tuple(lams))
+
+
+def normal_eq_state(kernel: Kernel, x: Array, cols: Array,
+                    landmark_idx: Array, *, tile: int | None = None,
+                    backend: str | None = None,
+                    interpret: bool | None = None,
+                    accumulator: str = "plain",
+                    precision: str | None = None) -> NormalEqState:
+    """The one pass over x: the normal-equation state of the landmarks
+    x[landmark_idx], without ever materializing K_nm.
+
+    ``cols`` is (n,) or (n, k): its columns ride the rhs of the same tile
+    stream (rhs (m,) or (m, k)) — a response, or a response beside the
+    score targets whose moments `pipeline.stages.score_moments` reads off
+    G and the rhs columns.  ``tile=None`` autotunes; ``precision`` picks
+    the Gram-contraction mode (`repro.core.precision`; None resolves it
+    jointly with an autotuned tile, or to "fp32" when the tile is pinned).
+    The state keeps the resolved plan, the row count and the per-chip
+    scan steps, so `solve_from_state` sets the truncation floor and
+    `normal_eq_absorb` continues the stream under the same plan.
+    """
+    _require_sentinel_safe(kernel)
+    xm = jnp.take(x, landmark_idx, axis=0)
+    autotuned = tile is None
+    with spans.span("repro/solve/plan"):
+        tile, precision, steps = _gram_exec(x, xm, tile, precision, backend,
+                                            accumulator)
+    with spans.span("repro/solve/gram"):
+        raw = _gram_normal_eq(kernel, x, cols, xm, tile=tile,
+                              autotuned=autotuned, backend=backend,
+                              interpret=interpret, accumulator=accumulator,
+                              precision=precision, finalize=False)
+        acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
+        return NormalEqState(
+            acc=accstate.wrap(accumulator, raw, rows=x.shape[0], steps=steps),
+            landmarks=xm, landmark_idx=landmark_idx,
+            # k_mm is O(m^2) work — the core path keeps it in the input
+            # dtype, which the dense solve also uses (dtype parity beats
+            # MXU here).
+            k_mm=kernel_matrix(kernel, xm).astype(acc_dtype),
+            tile=tile, backend=backend, interpret=interpret,
+            accumulator=accumulator, precision=precision)
+
+
+def normal_eq_response(state: NormalEqState, col: int = 0) -> NormalEqState:
+    """The single-response state of rhs column ``col`` of a widened state
+    (the form `normal_eq_absorb` continues with (n,) responses)."""
+    def take(tree):
+        g, rhs = tree
+        return g, rhs[:, col]
+
+    value = state.acc.value
+    if streaming.get(state.accumulator).name == "compensated":
+        value = tuple(take(part) for part in value)
+    else:
+        value = take(value)
+    return dataclasses.replace(
+        state, acc=dataclasses.replace(state.acc, value=value))
 
 
 def fit_streaming(
     kernel: Kernel,
     x: Array,
     y: Array,
-    lam: float,
+    lam: float | Sequence[float],
     landmark_idx: Array,
     *,
     tile: int | None = None,
@@ -682,234 +681,60 @@ def fit_streaming(
     precision: str | None = None,
     return_state: bool = False,
 ) -> NystromFit:
-    """`fit_from_landmarks` without ever materializing K_nm.
+    """`fit_from_landmarks` without ever materializing K_nm: the pass
+    (`normal_eq_state`) and the solve (`solve_from_state`).
 
     Matches the dense solve to fp32 reduction-order tolerance
     (tests/test_streaming_nystrom.py: <= 1e-4 relative on beta).
-    `weights` applies the without-replacement importance correction as a
-    post-accumulation O(m^2) column rescaling (`weighted_normal_eq`) — the
-    row stream itself is weight-free, so the Pallas/XLA accumulation kernels
-    are untouched.  `accumulator="compensated"` streams the Gram through the
-    two-float error-carrying sum (`repro.core.streaming`) and lowers the
-    solve's spectral noise floor to match — fp32 then keeps whitened
-    directions the plain accumulation must truncate.  ``precision`` picks
-    the Gram-contraction mode (`repro.core.precision`; None resolves it
-    jointly with an autotuned tile, or to "fp32" when the tile is pinned)
-    and scales the solve's truncation floor by `precision.EPS_SCALE`.
-
-    Internally this IS init + absorb + solve over a `NormalEqState`: the
-    one pass over x lands in first-class accumulator state (through the
-    same plan-keyed cached executable as before — the raw state is the
-    pre-finalize scan carry, and finalizing it outside is the identical
-    elementwise op), and the solve is `solve_from_state`.  Pass
-    ``return_state=True`` to also get the state back for incremental
-    `normal_eq_absorb` / `normal_eq_decay` updates — (fit, state) then.
+    `weights` applies the without-replacement importance correction after
+    the accumulation — the row stream itself is weight-free, so the
+    Pallas/XLA accumulation kernels are untouched.
+    `accumulator="compensated"` streams the Gram through the two-float
+    error-carrying sum (`repro.core.streaming`) and lowers the solve's
+    spectral noise floor to match — fp32 then keeps whitened directions
+    the plain accumulation must truncate; the precision mode scales the
+    floor by `precision.EPS_SCALE`.  A sequence of lams solves the one
+    state once per lam (`solve_from_state`).  Pass ``return_state=True``
+    to also get the state back for incremental `normal_eq_absorb` /
+    `normal_eq_decay` updates — (fit, state) then.
     """
-    _require_sentinel_safe(kernel)
-    n = x.shape[0]
-    xm = jnp.take(x, landmark_idx, axis=0)
-    autotuned = tile is None
-    with spans.span("repro/solve/plan"):
-        tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
-                                             accumulator)
-    with spans.span("repro/solve/gram"):
-        raw = _gram_normal_eq(kernel, x, y, xm, tile=tile,
-                              autotuned=autotuned, backend=backend,
-                              interpret=interpret, accumulator=accumulator,
-                              precision=precision, finalize=False)
-    acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
+    state = normal_eq_state(kernel, x, y, landmark_idx, tile=tile,
+                            backend=backend, interpret=interpret,
+                            accumulator=accumulator, precision=precision)
     with spans.span("repro/solve/whiten"):
-        state = NormalEqState(
-            acc=accstate.wrap(accumulator, raw, rows=n,
-                              steps=_scan_steps(n, tile, x, backend)),
-            landmarks=xm, landmark_idx=landmark_idx,
-            # k_mm is O(m^2) work — the core path keeps it in the input
-            # dtype, which the dense solve also uses (dtype parity beats
-            # MXU here).
-            k_mm=kernel_matrix(kernel, xm).astype(acc_dtype),
-            tile=tile, backend=backend, interpret=interpret,
-            accumulator=accumulator, precision=precision)
         fit_ = solve_from_state(state, lam, jitter=jitter, weights=weights)
     return (fit_, state) if return_state else fit_
 
 
-def fit_streaming_multi(
-    kernel: Kernel,
-    x: Array,
-    y: Array,
-    lams: Sequence[float],
-    landmark_idx: Array,
-    *,
-    tile: int | None = None,
-    backend: str | None = None,
-    interpret: bool | None = None,
-    jitter: float = 1e-6,
-    weights: Array | None = None,
-    accumulator: str = "plain",
-    precision: str | None = None,
-) -> list[NystromFit]:
-    """`fit_streaming` over a lam grid at ONE Gram-accumulation cost.
-
-    G = K_nm^T K_nm and rhs = K_nm^T y are lam-independent, and so is the
-    weighted column rescaling — only the O(m^3) whitened solve depends on
-    lam.  So the sweep streams the rows once (one psum per array under an
-    active mesh, exactly like the single-lam fit) and re-solves per lam via
-    `solve_normal_eq_multi`.  Fit i is bit-equal to
-    `fit_streaming(kernel, x, y, lams[i], landmark_idx, ...)`; cost is
-    O(n m (d + m)) + L·O(m^3) instead of L·O(n m (d + m)) — the whole point
-    of `pipeline.stages.CalibrateStage`.
-    """
-    _require_sentinel_safe(kernel)
-    n = x.shape[0]
-    xm = jnp.take(x, landmark_idx, axis=0)
-    autotuned = tile is None
-    tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
-                                         accumulator)
-    g, rhs = _gram_normal_eq(kernel, x, y, xm, tile=tile,
-                             autotuned=autotuned, backend=backend,
-                             interpret=interpret, accumulator=accumulator,
-                             precision=precision)
-    k_mm = kernel_matrix(kernel, xm).astype(g.dtype)
-    if weights is not None:
-        g, rhs, k_mm = weighted_normal_eq(g, rhs, k_mm, weights)
-    betas = solve_normal_eq_multi(
-        g, rhs, k_mm, n, lams, jitter=jitter,
-        eps_scale=_eff_eps_scale(accumulator,
-                                 _scan_steps(n, tile, x, backend), precision))
-    if weights is not None:
-        betas = weights.astype(betas.dtype)[None, :] * betas
-    return [NystromFit(beta=betas[i], landmarks=xm, landmark_idx=landmark_idx,
-                       lam=float(lam)) for i, lam in enumerate(lams)]
-
-
-def fit_streaming_scored(
-    kernel: Kernel,
-    x: Array,
-    y: Array,
-    lam: float,
-    landmark_idx: Array,
-    *,
-    f_star: Array | None = None,
-    tile: int | None = None,
-    backend: str | None = None,
-    interpret: bool | None = None,
-    jitter: float = 1e-6,
-    weights: Array | None = None,
-    accumulator: str = "plain",
-    precision: str | None = None,
-    return_state: bool = False,
-) -> tuple[NystromFit, dict]:
-    """`fit_streaming` + the in-sample score moments in ONE pass over x.
-
-    The in-sample MSE and risk are quadratic forms in quantities the Gram
-    stream already touches:
-
-        sum_i (f(x_i) - t_i)^2 = beta^T G beta - 2 beta^T (K_nm^T t) + t^T t
-
-    for targets t = y (MSE) or t = f_star (risk), with G = K_nm^T K_nm.
-    So instead of a separate predict pass, the extra responses ride the rhs
-    slot of the normal-equation stream as additional columns of w — one
-    widened (n, 1+r) aux through the SAME tile scan (`multi_reduce`
-    semantics via the widened rhs; Pallas carries the columns in its rhs
-    block).  Returns ``(fit, moments)`` where moments holds the
-    *unweighted* G, the per-target K_nm^T t columns, and the host-f64
-    t^T t scalars — `pipeline.stages.ScoreStage` assembles the scores in
-    f64 (the two big terms cancel to ~n * mse, so f64 assembly keeps ~7
-    significant digits of the score; locked at rtol 2e-3 against the
-    predict-pass scores in tests/test_multi_reduce.py).
-
-    Eager-only (host-computes t^T t); the pipeline's `evaluate()` is the
-    intended caller.  ``return_state=True`` appends a single-response
-    `NormalEqState` — the widened raw state with the extra score columns
-    sliced away — as a third element: (fit, moments, state).
-    """
-    _require_sentinel_safe(kernel)
-    n = x.shape[0]
-    xm = jnp.take(x, landmark_idx, axis=0)
-    autotuned = tile is None
-    tile, precision = _resolve_gram_exec(tile, precision, x, xm, backend,
-                                         accumulator)
-    cols = [jnp.asarray(y, x.dtype)]
-    if f_star is not None:
-        cols.append(jnp.asarray(f_star, x.dtype))
-    wmat = jnp.stack(cols, axis=1)                       # (n, 1 + r)
-    raw = _gram_normal_eq(kernel, x, wmat, xm, tile=tile,
-                          autotuned=autotuned, backend=backend,
-                          interpret=interpret, accumulator=accumulator,
-                          precision=precision, finalize=False)
-    g, rr = streaming.get(accumulator).finalize(raw)
-    rhs = rr[:, 0]
-    y64 = np.asarray(y, np.float64)
-    moments = {"g": g, "rhs_y": rr[:, 0], "n_eval": int(n),
-               "y_sq": float(y64 @ y64),
-               "rhs_f": None, "f_sq": None}
-    if f_star is not None:
-        f64 = np.asarray(f_star, np.float64)
-        moments["rhs_f"] = rr[:, 1]
-        moments["f_sq"] = float(f64 @ f64)
-    k_mm = kernel_matrix(kernel, xm).astype(g.dtype)
-    g_s, rhs_s, k_mm_s = g, rhs, k_mm
-    if weights is not None:
-        g_s, rhs_s, k_mm_s = weighted_normal_eq(g, rhs, k_mm, weights)
-    beta = solve_normal_eq(g_s, rhs_s, k_mm_s, n, lam, jitter=jitter,
-                           eps_scale=_eff_eps_scale(
-                               accumulator, _scan_steps(n, tile, x, backend),
-                               precision))
-    if weights is not None:
-        beta = weights.astype(beta.dtype) * beta
-    fit_ = NystromFit(beta=beta, landmarks=xm, landmark_idx=landmark_idx,
-                      lam=lam)
-    if not return_state:
-        return fit_, moments
-
-    def _col0(tree):
-        g_, rr_ = tree
-        return (g_, rr_[:, 0])
-
-    if streaming.get(accumulator).name == "compensated":
-        hi, lo = raw
-        value = (_col0(hi), _col0(lo))
-    else:
-        value = _col0(raw)
-    state = NormalEqState(
-        acc=accstate.wrap(accumulator, value, rows=n,
-                          steps=_scan_steps(n, tile, x, backend)),
-        landmarks=xm, landmark_idx=landmark_idx, k_mm=k_mm,
-        tile=tile, backend=backend, interpret=interpret,
-        accumulator=accumulator, precision=precision)
-    return fit_, moments, state
-
-
 def val_mse_streaming_multi(kernels: Sequence[Kernel],
-                            fits_by_h: Sequence[Sequence[NystromFit]],
+                            fits: Sequence[NystromFit],
                             x_val: Array, y_val: Array, *,
                             tile: int | None = None,
                             backend: str | None = None,
                             precision: str | None = None) -> Array:
     """Validation MSE for H bandwidth candidates x L lams in ONE x_val pass.
 
-    The calibration sweep historically re-streamed x_val once per bandwidth
-    (H `predict_streaming_multi` calls).  The per-h predictions only meet
-    at the end (a mean of squared errors), so the H reductions fuse into a
-    single `streaming.multi_reduce` scan: slot h builds its kernel tile
-    K_h(x_tile, X_m^h) once, applies all L betas as one matmul, and emits
-    the per-lam squared-error sums.  Returns the (H, L) val-MSE matrix;
-    each entry matches the sequential predict-then-mean to fp32
+    ``fits[h]`` is bandwidth h's lam-grid fit (`solve_from_state` over L
+    lams: beta (L, m) on one landmark set).  The per-h predictions only
+    meet at the end (a mean of squared errors), so the H reductions fuse
+    into a single `streaming.multi_reduce` scan: slot h builds its kernel
+    tile K_h(x_tile, X_m^h) once, applies all L betas as one matmul, and
+    emits the per-lam squared-error sums.  Returns the (H, L) val-MSE
+    matrix; each entry matches the sequential predict-then-mean to fp32
     reduction-order tolerance (same products, tile-major instead of
     row-major summation).  Mesh behavior: row slabs of x_val reduce
     locally, sums psum across chips (`streaming.mesh_reduce`).
     """
     from repro.kernels import dispatch
 
-    if len(kernels) != len(fits_by_h):
+    if len(kernels) != len(fits):
         raise ValueError("one kernel per bandwidth candidate required")
     for k in kernels:
         _require_sentinel_safe(k)
     n_val = x_val.shape[0]
-    big = len(fits_by_h)
-    xms = tuple(fits[0].landmarks for fits in fits_by_h)
-    betas = tuple(jnp.stack([f.beta for f in fits], axis=1)
-                  for fits in fits_by_h)                  # (m, L) each
+    big = len(fits)
+    xms = tuple(f.landmarks for f in fits)
+    betas = tuple(f.beta.T for f in fits)                 # (m, L) each
     acc = jnp.promote_types(x_val.dtype, jnp.float32)
     tile = _resolve_predict_tile(tile, x_val, xms[0], backend)
     multi = streaming.MultiAccumulator(("plain",) * big)
@@ -941,27 +766,6 @@ def val_mse_streaming_multi(kernels: Sequence[Kernel],
     return jnp.stack(sums) / n_val
 
 
-def predict_streaming_multi(kernel: Kernel, fits: Sequence[NystromFit],
-                            x_new: Array, *, tile: int | None = None,
-                            backend: str | None = None,
-                            precision: str | None = None) -> Array:
-    """Batched predict for several fits SHARING one landmark set: (L, n_new).
-
-    The kernel tile K(x_tile, X_m) is the expensive part of a predict and is
-    beta-independent, so a lam sweep evaluates it once per tile and applies
-    all betas as one (tile, m) x (m, L) matmul.  All fits must share
-    `landmarks` (the CalibrateStage invariant); mesh behavior matches
-    `predict_streaming` (purely local row slabs, `streaming.mesh_map`).
-    """
-    _require_sentinel_safe(kernel)
-    betas = jnp.stack([f.beta for f in fits], axis=1)     # (m, L)
-    xm = fits[0].landmarks
-    tile = _resolve_predict_tile(tile, x_new, xm, backend)
-    local = functools.partial(_predict_local, kernel=kernel, tile=tile,
-                              backend=backend, precision=precision)
-    return streaming.mesh_map(local, x_new, (xm, betas), out_rank=2).T
-
-
 def predict_streaming(kernel: Kernel, fit_: NystromFit, x_new: Array,
                       *, tile: int | None = None,
                       backend: str | None = None,
@@ -989,7 +793,7 @@ def predict_streaming(kernel: Kernel, fit_: NystromFit, x_new: Array,
 
 def _predict_local(x_loc, xm, beta, *, kernel, tile, backend, precision):
     """One chip's predictions K(x_loc, X_m) beta, beta (m,) or (m, L), tile
-    by tile: the body of `predict_streaming[_multi]` under
+    by tile: the body of `predict_streaming` under
     `streaming.mesh_map` (module level, so its program is reused)."""
     from repro.kernels import dispatch
 
@@ -1001,6 +805,21 @@ def _predict_local(x_loc, xm, beta, *, kernel, tile, backend, precision):
 
 
 # ------------------------------------------------- many-model batched fits --
+
+def _batched_local(x_loc, yst_loc, xms_loc, *, kernel, tile, accumulator,
+                   precision):
+    """One chip's raw per-model (G, rhs) states: `scan_normal_eq` vmapped
+    over the chip's landmark sets and response columns, so each tile of
+    x_loc is loaded once for every model — the body `fit_streaming_batched`
+    hands `streaming.mesh_reduce` (module level, so its program is
+    reused)."""
+    def one(xm, y):
+        return scan_normal_eq(kernel, x_loc, xm, y, tile=tile,
+                              accumulator=accumulator, precision=precision,
+                              finalize=False)
+
+    return jax.vmap(one, in_axes=(0, 1))(xms_loc, yst_loc)
+
 
 class BatchedNystromFit(NamedTuple):
     """B independent KRR models fit in one program (the many-tenant case).
@@ -1023,51 +842,6 @@ def _models_per_chip(num_models: int) -> int:
     (`dispatch.resolve_plan(num_models=...)`: each tile step holds one
     (tile, m) kernel slab per local model)."""
     return max(1, num_models // streaming.model_shard_count(num_models))
-
-
-def solve_normal_eq_batched(gs: Array, rhss: Array, k_mms: Array, n: int,
-                            lams: Array, jitter: float = 1e-6,
-                            eps_scale: float = 1.0) -> Array:
-    """Per-model whitened solves over a leading model axis: (B, m) betas.
-
-    Unlike the lam sweep (`solve_normal_eq_multi`, which shares one
-    eigendecomposition), every model here owns its landmark set, so each
-    gets its own O(m^3) eigh — vmapped into one batched LAPACK/XLA call.
-    Under an active mesh whose "models" rule divides B the batch SHARDS
-    over the model axis (each chip column decomposes and solves only its
-    B / M models); otherwise the vmap runs replicated.  Model b matches
-    `solve_normal_eq(gs[b], rhss[b], k_mms[b], n, lams[b])` to reduction-
-    order tolerance (the n*lam product rounds on device here).
-    """
-    eps = float(jnp.finfo(gs.dtype).eps) * eps_scale
-    # n * lam in HOST float64, rounded once to the Gram dtype — the same
-    # rounding the scalar path's weak promotion applies (`_whitened_solve`
-    # with a python lam).  A device-side f32 product rounds differently at
-    # the ulp level, which is enough to flip the truncation threshold tau
-    # on near-threshold eigendirections and blow the per-model beta parity
-    import numpy as _np
-    nlams = jnp.asarray(
-        _np.asarray(n, _np.float64) * _np.asarray(jax.device_get(lams),
-                                                  _np.float64),
-        gs.dtype)
-
-    def batch(g_b, rhs_b, k_b, nlam_b):
-        def one(g, rhs, k_mm, nlam):
-            evals, evecs = jnp.linalg.eigh(k_mm)
-            return _whitened_solve_nlam(g, rhs, evals, evecs, jnp.trace(g),
-                                        nlam, jitter, eps)
-
-        return jax.vmap(one)(g_b, rhs_b, k_b, nlam_b)
-
-    mesh, model_axes = streaming._active_axes("models", (gs.shape[0],))
-    if mesh is None:
-        return batch(gs, rhss, k_mms, nlams)
-    from jax.sharding import PartitionSpec as P
-    return jax.shard_map(
-        batch, mesh=mesh,
-        in_specs=(P(model_axes), P(model_axes), P(model_axes),
-                  P(model_axes)),
-        out_specs=P(model_axes))(gs, rhss, k_mms, nlams)
 
 
 def fit_streaming_batched(
@@ -1093,16 +867,18 @@ def fit_streaming_batched(
     `fit_streaming` would stream x B times; here the per-model normal
     equations G_b = K_nm(b)^T K_nm(b), rhs_b = K_nm(b)^T y_b accumulate
     through ONE `streaming.mesh_reduce` tile scan — each tile's rows are
-    loaded once and contracted against all locally held models (vmap over
-    the model axis inside the scan body), so the stream cost is paid once
-    and the arithmetic scales as B times the per-model contraction only.
+    loaded once and contracted against all locally held models
+    (`scan_normal_eq` vmapped over the models), so the stream cost is paid
+    once and the arithmetic scales as B times the per-model contraction
+    only.
 
     Mesh semantics (`repro.distributed.sharding`): rows shard over "rows"
     (data axis, psummed), models over "models" (model axis, independent) —
     on a 2D (data, model) mesh a B=256 batch on M=16 model shards holds 16
     models per chip column.  ``ys`` is the dual-sharded (rows, models)
     operand (transposed internally); landmark sets and the per-model solves
-    (`solve_normal_eq_batched`) shard over the model axis.  With no mesh
+    (`solve_normal_eq`'s `_solve` vmapped over the models, through
+    `streaming.mesh_map`) shard over the model axis.  With no mesh
     (or a 1D data mesh) everything model-wise is replicated and only the
     rows shard — the transparent-fallback contract.
 
@@ -1122,7 +898,7 @@ def fit_streaming_batched(
     if landmark_sets.ndim != 2:
         raise ValueError(f"landmark_sets must be (B, m) indices, got shape "
                          f"{landmark_sets.shape}")
-    big, m = landmark_sets.shape
+    big = landmark_sets.shape[0]
     ys = jnp.asarray(ys, x.dtype)
     if ys.ndim == 1:
         ys = jnp.broadcast_to(ys[None, :], (big, n))
@@ -1133,32 +909,12 @@ def fit_streaming_batched(
     xms = jnp.take(x, landmark_sets, axis=0)              # (B, m, d)
     acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
 
-    tile, precision = _resolve_gram_exec(tile, precision, x, xms[0], "xla",
-                                         accumulator,
-                                         num_models=_models_per_chip(big))
+    tile, precision, steps = _gram_exec(x, xms[0], tile, precision, "xla",
+                                        accumulator,
+                                        num_models=_models_per_chip(big))
     ys_t = ys.T.astype(acc_dtype)                         # (n, B) dual-shard
-
-    def local(x_loc, yst_loc, xms_loc):
-        b_loc = xms_loc.shape[0]
-
-        def emit(xt, yt):                                 # (t, d), (t, b_loc)
-            def one(xm_b, y_col):
-                k = kernel_matrix(kernel, xt, xm_b).astype(acc_dtype)
-                g = precision_mod.split_dot(k, k, (((0,), (0,)), ((), ())),
-                                            precision=precision,
-                                            acc=acc_dtype)
-                r = jax.lax.dot_general(k, y_col, (((0,), (0,)), ((), ())),
-                                        preferred_element_type=acc_dtype)
-                return g, r
-
-            return jax.vmap(one, in_axes=(0, 1))(xms_loc, yt)
-
-        init = (jnp.zeros((b_loc, m, m), acc_dtype),
-                jnp.zeros((b_loc, m), acc_dtype))
-        return streaming.tile_reduce(emit, x_loc, (yst_loc,), tile=tile,
-                                     init=init, accumulator=accumulator,
-                                     pad="sentinel", finalize=False)
-
+    local = functools.partial(_batched_local, kernel=kernel, tile=tile,
+                              accumulator=accumulator, precision=precision)
     gs, rhss = streaming.mesh_reduce(local, (x,), model_args=(xms,),
                                      row_model_args=(ys_t,),
                                      accumulator=accumulator, finalize=True)
@@ -1167,10 +923,15 @@ def fit_streaming_batched(
     if weights is not None:
         gs, rhss, k_mms = jax.vmap(weighted_normal_eq)(
             gs, rhss, k_mms, weights)
-    betas = solve_normal_eq_batched(
-        gs, rhss, k_mms, n, lams, jitter=jitter,
-        eps_scale=_eff_eps_scale(accumulator,
-                                 _scan_steps(n, tile, x, "xla"), precision))
+    # n*lam in HOST float64, rounded once to the Gram dtype: the rounding
+    # the single-model solve's python n*lam gets where it meets G
+    nlams = jnp.asarray(n * np.asarray(jax.device_get(lams), np.float64),
+                        gs.dtype)
+    eps = float(jnp.finfo(gs.dtype).eps) * _eff_eps_scale(
+        accumulator, steps, precision)
+    body = functools.partial(_solve_models, jitter=jitter, eps=eps)
+    betas = streaming.mesh_map(body, (nlams, gs, rhss, k_mms), out_rank=2,
+                               rule="models")
     if weights is not None:
         betas = weights.astype(betas.dtype) * betas
     return BatchedNystromFit(beta=betas, landmarks=xms,
@@ -1183,9 +944,9 @@ def predict_streaming_batched(kernel: Kernel, fit_: BatchedNystromFit,
                               precision: str | None = None) -> Array:
     """Predict all B models on shared query rows in one pass: (B, n_new).
 
-    Unlike `predict_streaming_multi` (many betas, ONE landmark set) every
-    model here owns its landmarks, so each tile evaluates B_loc kernel
-    slabs — rows still stream once.  Mesh layout matches the batched fit:
+    Unlike a lam grid (many betas, ONE landmark set) every model here
+    owns its landmarks, so each tile evaluates B_loc kernel slabs — rows
+    still stream once.  Mesh layout matches the batched fit:
     rows shard over "rows", models over "models"
     (`streaming.mesh_map(model_args=...)` — output dim 0 rides the model
     axis, dim 1 the rows); no collective, predict is row-parallel per model.

@@ -2,13 +2,13 @@
 
 Every streaming loop in this codebase reduces (or maps) row slabs of an
 (n, ...) array through a per-tile computation: the Nystrom normal equations
-(`nystrom.scan_normal_eq` and its mesh-sharded wrapper), the CIC deposit of
-the binned KDE (`kde.scatter_cic` and its per-chip twin in
-`core.distributed.kde_binned_sharded_multi`), and the batched predicts
-(`nystrom.predict_streaming[_multi]`).  Historically each re-implemented
-tiling, ragged-tail padding, sharding and accumulation by hand — every
-numerics fix (e.g. EXACT_DIST_D) had to land in four places.  This module
-owns that plumbing once:
+(`nystrom.normal_eq_state`'s pass: `nystrom.scan_normal_eq` and its
+mesh-sharded wrapper), the CIC deposit of the binned KDE (`kde.scatter_cic`
+and its per-chip twin in `core.distributed.kde_binned_sharded_multi`), and
+the batched predicts (`nystrom.predict_streaming[_batched]`).  Historically
+each re-implemented tiling, ragged-tail padding, sharding and accumulation
+by hand — every numerics fix (e.g. EXACT_DIST_D) had to land in four
+places.  This module owns that plumbing once:
 
   * `tile_reduce`  — row-slab tiling, ragged-tail padding (sentinel rows for
     kernel maps, zero rows + zero weights for deposits), a `lax.scan` over
@@ -30,8 +30,8 @@ Accumulator strategies (`get(name)`):
     an error-free two-sum and the rounding error is banked in lo.  The pair
     survives the cross-chip psum (hi and lo reduce separately) and is only
     collapsed by `finalize`.  Cross-tile accumulation error drops from
-    O(steps) * eps to the within-tile floor, which lets
-    `nystrom.solve_normal_eq` lower its spectral noise-floor cutoff by
+    O(steps) * eps to the within-tile floor, which lets the solve
+    (`nystrom.solve_from_state`) lower its spectral noise-floor cutoff by
     `EPS_SCALE["compensated"]` and keep whitened directions that plain fp32
     must truncate (ROADMAP: the fp32 scale ceiling).
 
@@ -57,7 +57,8 @@ Array = jax.Array
 ACCUMULATORS = ("plain", "compensated")
 
 # Residual accumulation-noise scale, relative to eps(dtype), that
-# `nystrom.solve_normal_eq` may assume for a Gram built by each strategy.
+# the solve (`nystrom.solve_from_state`) may assume for a Gram built by each
+# strategy.
 # Plain fp32 accumulation noise sits at ~eps * lambda_max(G); the
 # compensated sum removes the cross-tile term, leaving the within-tile dot
 # rounding — measured ≳30x below the plain floor on the n ≥ 1e5 streams the
@@ -223,7 +224,8 @@ def get(accumulator: str | Any) -> Any:
 
 
 def eps_scale(accumulator: str | Any, steps: int | None = None) -> float:
-    """Noise-floor scale for `nystrom.solve_normal_eq` (see EPS_SCALE).
+    """Noise-floor scale for the solve (`nystrom.solve_from_state`, through
+    `nystrom.solve_normal_eq`'s ``eps_scale``; see EPS_SCALE).
 
     Compensation removes only the CROSS-TILE accumulation error, so the
     floor may recede by at most the number of scan steps the stream ran:
@@ -588,16 +590,20 @@ def mesh_reduce(
 
 def mesh_map(
     local: Callable[..., Array],
-    x: Array,
+    x: Array | Sequence[Array],
     rep_args: Sequence[Array] = (),
     *,
     model_args: Sequence[Array] = (),
     out_rank: int = 1,
+    rule: str = "rows",
 ) -> Array:
-    """Row-sharded map: `local(x_loc, *rep_args)` -> (n_loc, ...) per chip.
+    """Sharded map: `local(x_loc, *rep_args)` -> (n_loc, ...) per chip.
 
-    Embarrassingly row-parallel (no collective); `out_rank` is the rank of
-    local's output, whose leading dim stays row-sharded.  With no active
+    Embarrassingly parallel (no collective); `out_rank` is the rank of
+    local's output, whose leading dim stays split.  ``rule`` names the
+    logical axis that splits dim 0 of x: "rows" (the default) or "models"
+    (independent work, such as a model batch).  ``x`` may be a tuple of
+    arrays sharing that dim, passed to `local` in order.  With no active
     mesh (or a non-dividing axis) this is `local(x, *rep_args)`.  Under a
     mesh it runs as one jitted ``shard_map`` program, compiled once and
     reused on the terms `mesh_reduce` states.
@@ -610,22 +616,23 @@ def mesh_map(
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh, axes = _active_rows(x.shape)
+    xs = tuple(x) if isinstance(x, (tuple, list)) else (x,)
+    mesh, axes = _active_axes(rule, xs[0].shape)
     model_axes = None
     if model_args:
         m_mesh, model_axes = _active_axes("models", model_args[0].shape)
         if mesh is None and m_mesh is not None:
             mesh = m_mesh
     if mesh is None:
-        return local(x, *model_args, *rep_args)
-    in_specs = ((_row_spec(axes, x.ndim),)
+        return local(*xs, *model_args, *rep_args)
+    in_specs = (tuple(_row_spec(axes, a.ndim) for a in xs)
                 + tuple(_row_spec(model_axes, a.ndim) for a in model_args)
                 + tuple(P(*([None] * a.ndim)) for a in rep_args))
     if model_args:
         out_specs = P(model_axes, axes, *([None] * (out_rank - 2)))
     else:
         out_specs = _row_spec(axes, out_rank)
-    return _program(local, mesh, in_specs, out_specs)(x, *model_args,
+    return _program(local, mesh, in_specs, out_specs)(*xs, *model_args,
                                                       *rep_args)
 
 

@@ -24,8 +24,8 @@ the MXU rank-bm update of the Gram block:
     pairs (`repro.core.streaming` semantics in-kernel): each rank-bm update
     is folded in with Knuth's TwoSum and the rounding error banked in the
     lo block — the cross-tile fp32 accumulation error disappears, which is
-    what lets `nystrom.solve_normal_eq` lower its spectral truncation floor
-    on the TPU path exactly like the XLA engine path.
+    what lets the solve (`nystrom.solve_from_state`) lower its spectral
+    truncation floor on the TPU path exactly like the XLA engine path.
 
 Padded rows are placed at the ROW_SENTINEL coordinate by ops.py: their
 distance to any real landmark is ~1e6, and every kernel map underflows

@@ -219,62 +219,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str | None,
     return rec
 
 
-def krr_model_flops(n: int, d: int, m: int, m_kde: int) -> float:
-    """Useful flops of the SA+Nyström pipeline (global, per §Roofline)."""
-    kde = 2.0 * n * m_kde * d
-    k_nm = 2.0 * n * m * d
-    normal_eq = 2.0 * n * m * m
-    solve = (2.0 / 3.0) * m ** 3
-    fitted = 2.0 * n * m
-    return kde + k_nm + normal_eq + solve + fitted
-
-
-def run_krr_cell(mesh_name: str, out_dir: str | None, n: int = 1 << 24,
-                 d: int = 3, kde_method: str = "direct") -> dict:
-    """Dry-run the paper's own pipeline (core/distributed.py) on the mesh."""
-    from repro.core import distributed as D
-    mesh = mesh_lib.make_production_mesh(
-        multi_pod=(mesh_name == "multi"),
-        num_devices=512 if mesh_name == "multi" else 256)
-    chips = mesh.devices.size
-    m = int(5 * n ** (1.0 / 3.0))
-    m_kde = max(1024, int(n ** 0.5))
-    t0 = time.perf_counter()
-    lowered, compiled = D.lower_pipeline(mesh, n=n, d=d, m=m, m_kde=m_kde,
-                                         kde_method=kde_method)
-    cost = roofline.cost_dict(compiled)
-    coll = roofline.collective_bytes(compiled.as_text() or "")
-    try:
-        mem_str = str(compiled.memory_analysis())
-    except Exception as e:  # pragma: no cover
-        mem_str = f"<unavailable: {e}>"
-    tag = "+binned" if kde_method == "binned" else ""
-    r = roofline.Roofline(
-        arch="krr-sa-pipeline" + tag, shape=f"n{n}", mesh=mesh_name,
-        chips=chips,
-        device_flops=float(cost.get("flops", 0.0)),
-        device_bytes=float(cost.get("bytes accessed", 0.0)),
-        device_collective_bytes=float(sum(coll.values())),
-        collective_breakdown={k: float(v) for k, v in coll.items()},
-        model_flops_global=krr_model_flops(n, d, m, m_kde),
-    )
-    rec = r.to_dict()
-    rec.update(status="ok", compile_seconds=time.perf_counter() - t0,
-               memory_analysis=mem_str, n=n, d=d, m=m, m_kde=m_kde)
-    print(f"[{mesh_name}] krr-sa-pipeline n={n}: OK | "
-          f"flops/dev={r.device_flops:.3e} bytes/dev={r.device_bytes:.3e} "
-          f"coll/dev={r.device_collective_bytes:.3e} bound={r.bottleneck} "
-          f"useful={r.useful_flops_ratio:.2f} "
-          f"roofline={100*r.roofline_fraction:.1f}%")
-    if out_dir:
-        os.makedirs(out_dir, exist_ok=True)
-        with open(os.path.join(out_dir,
-                               f"{mesh_name}__krr-sa-pipeline{tag}__n{n}.json"),
-                  "w") as f:
-            json.dump(rec, f, indent=1)
-    return rec
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(configs.ARCHS))
@@ -282,21 +226,10 @@ def main() -> None:
     ap.add_argument("--mesh", default="single", choices=["single", "multi",
                                                          "both"])
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--krr", action="store_true",
-                    help="dry-run the paper's SA+Nyström pipeline cell")
-    ap.add_argument("--kde-method", default="direct",
-                    choices=["direct", "binned"],
-                    help="KRR cell KDE substrate (binned = §Perf optimized)")
     ap.add_argument("--sp-prefill", action="store_true",
                     help="sequence-parallel prefill rules (§Perf optimized)")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
-
-    if args.krr:
-        meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
-        for mesh_name in meshes:
-            run_krr_cell(mesh_name, args.out, kde_method=args.kde_method)
-        return
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     if args.all:

@@ -355,7 +355,7 @@ class OnlineLandmarks:
         g = k.T @ (w[:, None] * k)
         rhs = k.T @ (w * jnp.asarray(self.y, k.dtype))
         n_eff = float(np.sum(self.weights))
-        beta = nystrom.solve_normal_eq(g, rhs, k, n_eff, lam, jitter)
+        beta = nystrom.solve_normal_eq(g, rhs, k, n_eff, lam, jitter=jitter)
         return nystrom.NystromFit(beta=beta, landmarks=xd,
                                   landmark_idx=jnp.asarray(self.idx,
                                                            jnp.int32),

@@ -23,8 +23,8 @@ defaults; 'auto' resolves from the platform inside `repro.kernels.dispatch`
 Sharding: stages are mesh-aware through `repro.distributed.sharding`.
 Under an active mesh, DensityStage routes the binned KDE through
 `core.distributed.kde_binned_sharded` (rows scattered locally, one grid
-psum) and SolveStage's `nystrom.fit_streaming` shards the normal-equation
-row stream; with no mesh both run single-device, same numbers.
+psum) and SolveStage's normal-equation pass (`nystrom.normal_eq_state`)
+shards its row stream; with no mesh both run single-device, same numbers.
 """
 
 from __future__ import annotations
@@ -38,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import spans
-from repro.core import kde, kernels, leverage, nystrom, sampling, streaming
+from repro.core import (accstate, kde, kernels, leverage, nystrom, sampling,
+                        streaming)
 
 Array = jax.Array
 
@@ -323,11 +324,11 @@ class SolveStage(Stage):
     ("fp32" | "bf16x2" | "bf16x3" | None, default from the config) picks
     the Gram-contraction mode (`repro.core.precision`).
 
-    Under ``ctx.fuse_scoring`` with in-sample evaluation inputs, the stage
-    runs `nystrom.fit_streaming_scored` instead: the score targets ride the
-    rhs of the SAME Gram stream and the quadratic-form moments land on
-    ``ctx.score_moments`` — PredictStage/ScoreStage then finish the fold
-    without re-streaming x."""
+    Under ``ctx.fuse_scoring`` with in-sample evaluation inputs, the
+    score targets ride the rhs of the SAME pass as extra columns
+    (`nystrom.normal_eq_state` over [y, f_star]) and the quadratic-form
+    moments land on ``ctx.score_moments`` (`score_moments`) —
+    PredictStage/ScoreStage then finish the fold without re-streaming x."""
 
     name = "solve"
     requires = ("landmark_idx",)
@@ -368,19 +369,50 @@ class SolveStage(Stage):
         cfg = ctx.config
         weights = ctx.sample_weights if self.weighted else None
         backend, tile, accumulator, precision = resolve_exec(self, cfg)
-        if self._fuse(ctx):
-            ctx.fit, ctx.score_moments, ctx.solve_state = (
-                nystrom.fit_streaming_scored(
-                    ctx.kernel, ctx.x, ctx.y, ctx.lam, ctx.landmark_idx,
-                    f_star=ctx.f_star, tile=tile, backend=backend,
-                    jitter=cfg.jitter, weights=weights,
-                    accumulator=accumulator, precision=precision,
-                    return_state=True))
+        if not self._fuse(ctx):
+            ctx.fit, ctx.solve_state = nystrom.fit_streaming(
+                ctx.kernel, ctx.x, ctx.y, ctx.lam, ctx.landmark_idx,
+                tile=tile, backend=backend, jitter=cfg.jitter,
+                weights=weights, accumulator=accumulator,
+                precision=precision, return_state=True)
             return
-        ctx.fit, ctx.solve_state = nystrom.fit_streaming(
-            ctx.kernel, ctx.x, ctx.y, ctx.lam, ctx.landmark_idx,
-            tile=tile, backend=backend, jitter=cfg.jitter, weights=weights,
-            accumulator=accumulator, precision=precision, return_state=True)
+        targets = [t for t in (ctx.y, ctx.f_star) if t is not None]
+        cols = jnp.stack([jnp.asarray(t, ctx.x.dtype) for t in targets],
+                         axis=1)                          # (n, 1 + r)
+        state = nystrom.normal_eq_state(
+            ctx.kernel, ctx.x, cols, ctx.landmark_idx, tile=tile,
+            backend=backend, accumulator=accumulator, precision=precision)
+        ctx.score_moments = score_moments(state, ctx.y, ctx.f_star)
+        ctx.solve_state = nystrom.normal_eq_response(state)
+        ctx.fit = nystrom.solve_from_state(ctx.solve_state, ctx.lam,
+                                           jitter=cfg.jitter, weights=weights)
+
+
+def score_moments(state: nystrom.NormalEqState, y: Array,
+                  f_star: Array | None) -> dict:
+    """The in-sample score moments of a state whose rhs columns are
+    K_nm^T [y, f_star]: the *unweighted* G, one K_nm^T t column per target
+    and the host-f64 t^T t scalars.
+
+    The in-sample MSE and risk are quadratic forms in what the pass
+    already holds,
+
+        sum_i (f(x_i) - t_i)^2 = beta^T G beta - 2 beta^T (K_nm^T t) + t^T t,
+
+    so ScoreStage assembles them in f64 from these instead of a predict
+    pass (the two big terms cancel to ~n * mse, so f64 assembly keeps ~7
+    significant digits of the score; locked at rtol 2e-3 against the
+    predict-pass scores in tests/test_multi_reduce.py).
+    """
+    g, rhs = accstate.finalize(state.acc)
+    y64 = np.asarray(y, np.float64)
+    moments = {"g": g, "rhs_y": rhs[:, 0], "n_eval": int(y64.shape[0]),
+               "y_sq": float(y64 @ y64), "rhs_f": None, "f_sq": None}
+    if f_star is not None:
+        f64 = np.asarray(f_star, np.float64)
+        moments["rhs_f"] = rhs[:, 1]
+        moments["f_sq"] = float(f64 @ f64)
+    return moments
 
 
 class BatchedSampleStage(Stage):
@@ -605,9 +637,11 @@ class CalibrateStage(Stage):
     The sweep's costs factor exactly:
 
       * the tiled Gram ``K_nm^T K_nm`` / moments are lam-independent, so each
-        bandwidth candidate accumulates them ONCE and re-solves the whitened
-        normal equations per lam (`nystrom.fit_streaming_multi` — bit-equal
-        to per-lam `fit_streaming` loops, at 1/L of the row-stream cost);
+        bandwidth candidate accumulates them ONCE and re-solves the
+        whitened normal equations per lam (`nystrom.fit_streaming` over the
+        lam grid: one `normal_eq_state` pass, one `solve_from_state` —
+        bit-equal to per-lam `fit_streaming` loops, at 1/L of the
+        row-stream cost);
       * the binned-KDE CIC deposit is h-independent on a fixed grid, so the
         whole bandwidth grid shares ONE deposit and only the FFT smooth +
         gather re-run per h (`kde.kde_binned_multi`;
@@ -781,7 +815,7 @@ class CalibrateStage(Stage):
         # re-derived from the key inside every per-h call)
         race_dtype = jnp.promote_types(ctx.x.dtype, jnp.float32)
         race = jax.random.gumbel(key, (n_tr,), dtype=race_dtype)
-        fits_by_h: list[list] = []
+        fits_by_h: list[nystrom.NystromFit] = []
         fit_seconds: list[float] = []
         h_seconds: list[float] = []
         for i, h in enumerate(h_grid):
@@ -801,17 +835,17 @@ class CalibrateStage(Stage):
                         sampling.sample_weighted_without_replacement(
                             key, lev.probs, m, gumbel=race))
                 with spans.span("repro/calibrate/solve") as fit_span:
-                    fits = nystrom.fit_streaming_multi(
+                    grid = nystrom.fit_streaming(
                         ctx.kernel, x_tr, y_tr, lam_grid, idx,
                         tile=tile, backend=backend, jitter=cfg.jitter,
                         weights=weights if self.weighted else None,
                         accumulator=accumulator, precision=precision)
-                    jax.block_until_ready(fits[0].beta)
+                    jax.block_until_ready(grid.beta)
             sec_key = f"calibrate[{tag}h={h:.3g}]"
             if sec_key in ctx.seconds:   # grid values equal at 3 sig figs
                 sec_key = f"calibrate[{tag}h={h:.3g}#{i}]"
             ctx.seconds[sec_key] = h_span.seconds
-            fits_by_h.append(fits)
+            fits_by_h.append(grid)
             fit_seconds.append(fit_span.seconds)
             h_seconds.append(h_span.seconds)
         # validation: ONE fused x_val stream scores every (h, lam) candidate
@@ -919,7 +953,7 @@ def resolve_exec(stage: Any, cfg: Any, *, tile_attr: str = "tile",
     (`repro.tuning` through `kernels.dispatch.resolve_plan`); a resolved
     precision of None means the Gram stream picks its (tile, precision)
     pair jointly from the autotune plan — or the historical "fp32" when
-    the tile is pinned (`nystrom._resolve_gram_exec`).  Stages without a
+    the tile is pinned (`nystrom._gram_exec`).  Stages without a
     Gram contraction (KDE deposit) ignore the precision slot.
     """
     backend = getattr(stage, "backend", None)

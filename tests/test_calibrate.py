@@ -44,7 +44,7 @@ def test_solve_normal_eq_multi_bit_matches_single():
     xm = data.x[idx]
     g, rhs = nystrom.streaming_normal_eq(kern, data.x, data.y, xm, tile=512)
     k_mm = K.kernel_matrix(kern, xm).astype(g.dtype)
-    betas = nystrom.solve_normal_eq_multi(g, rhs, k_mm, 2048, LAMS)
+    betas = nystrom.solve_normal_eq(g, rhs, k_mm, 2048, LAMS)
     assert betas.shape == (len(LAMS), 64)
     for i, lam in enumerate(LAMS):
         want = nystrom.solve_normal_eq(g, rhs, k_mm, 2048, lam)
@@ -59,13 +59,13 @@ def test_fit_streaming_multi_bit_matches_per_lam_loop(weighted):
     kern = K.Matern(nu=1.5)
     idx = _landmarks(2048)
     w = (1.0 + jnp.arange(64, dtype=jnp.float32) / 16.0) if weighted else None
-    fits = nystrom.fit_streaming_multi(kern, data.x, data.y, LAMS, idx,
-                                       tile=512, weights=w)
-    assert [f.lam for f in fits] == LAMS
-    for lam, fit in zip(LAMS, fits):
+    state = nystrom.normal_eq_state(kern, data.x, data.y, idx, tile=512)
+    grid = nystrom.solve_from_state(state, LAMS, weights=w)
+    assert list(grid.lam) == LAMS
+    for i, lam in enumerate(LAMS):
         ref = nystrom.fit_streaming(kern, data.x, data.y, lam, idx,
                                     tile=512, weights=w)
-        np.testing.assert_array_equal(np.asarray(fit.beta),
+        np.testing.assert_array_equal(np.asarray(grid.beta[i]),
                                       np.asarray(ref.beta))
 
 
@@ -73,12 +73,14 @@ def test_predict_streaming_multi_matches_per_fit():
     data = _data(seed=2)
     kern = K.Matern(nu=1.5)
     idx = _landmarks(2048)
-    fits = nystrom.fit_streaming_multi(kern, data.x, data.y, LAMS, idx,
-                                       tile=512)
-    preds = nystrom.predict_streaming_multi(kern, fits, data.x[:300],
-                                            tile=128)
+    state = nystrom.normal_eq_state(kern, data.x, data.y, idx, tile=512)
+    grid = nystrom.solve_from_state(state, LAMS)
+    # one kernel tile per slab, all L betas applied as one (m, L) matmul
+    preds = nystrom.predict_streaming(kern, grid._replace(beta=grid.beta.T),
+                                      data.x[:300], tile=128).T
     assert preds.shape == (len(LAMS), 300)
-    for i, fit in enumerate(fits):
+    for i, lam in enumerate(LAMS):
+        fit = nystrom.solve_from_state(state, lam)
         want = np.asarray(nystrom.predict_streaming(kern, fit, data.x[:300],
                                                     tile=128))
         np.testing.assert_allclose(np.asarray(preds[i]), want,

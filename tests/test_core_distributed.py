@@ -1,5 +1,7 @@
-"""Sharded SA+Nyström pipeline == single-device reference (subprocess,
-8 forced host devices), plus an abstract lowering check."""
+"""The pipeline's sharded paths on forced host devices (subprocesses): the
+sharded binned KDE against its oracle, the sharded fit's leverage against
+the one-device fold (8 devices), and the sharded fit's Gram program on a
+(data, model) mesh (4 devices)."""
 
 from __future__ import annotations
 
@@ -13,10 +15,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_sub(body: str) -> str:
-    code = textwrap.dedent("""
+def run_sub(body: str, devices: int = 8) -> str:
+    code = textwrap.dedent(f"""
         import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["XLA_FLAGS"] = \
+            "--xla_force_host_platform_device_count={devices}"
         import jax
         import jax.numpy as jnp
     """) + textwrap.dedent(body)
@@ -28,50 +31,35 @@ def run_sub(body: str) -> str:
 
 
 @pytest.mark.slow
-def test_sharded_pipeline_matches_reference():
+def test_sharded_fit_leverage_matches_one_device():
+    """SAKRRPipeline.fit on an 8-device data mesh: the SA sampling
+    distribution (lattice psum, global bounds, normalised leverage) matches
+    the one-device fold's."""
     out = run_sub("""
         import numpy as np
-        from repro.core import distributed as D
-        from repro.core import kernels as K
-        from repro.core import kde as core_kde
-        from repro.core import leverage, nystrom
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.data import krr_data
         from repro.distributed import sharding as shd
         from repro.launch import mesh as mesh_lib
+        from repro.pipeline import PipelineConfig, SAKRRPipeline
 
-        n, d, m, m_kde = 1024, 3, 32, 256
-        lam = 0.075 * n ** (-2/3)
-        h = 0.3
-        data = krr_data.bimodal(jax.random.PRNGKey(0), n, d=d)
-        kde_sample = data.x[:m_kde]
-        idx = jnp.arange(0, n, n // m)[:m]
-        kern = K.Matern(nu=1.5)
-        fn = D.make_pipeline_fn(kern, lam, h)
-
-        # single-device reference
-        ref = jax.jit(fn)(data.x, data.y, kde_sample, idx)
+        data = krr_data.bimodal(jax.random.PRNGKey(0), 4096, d=3)
+        cfg = PipelineConfig(num_landmarks=32, kde_grid_size=48, tile=512)
+        ref = SAKRRPipeline(cfg).fit(data.x, data.y)
 
         mesh = mesh_lib.make_local_mesh(devices=jax.devices()[:8])
-        with shd.activate(mesh, {"batch": ("data",)}):
-            sh = jax.jit(fn)(data.x, data.y, kde_sample, idx)
+        x = jax.device_put(data.x, NamedSharding(mesh, P("data", None)))
+        y = jax.device_put(data.y, NamedSharding(mesh, P("data")))
+        with shd.activate(mesh):
+            sh = SAKRRPipeline(cfg).fit(x, y)
 
-        np.testing.assert_allclose(np.asarray(ref.probs), np.asarray(sh.probs),
+        np.testing.assert_allclose(np.asarray(sh.state.leverage.probs),
+                                   np.asarray(ref.state.leverage.probs),
                                    rtol=2e-4, atol=1e-9)
-        # beta itself is near-null-space sensitive (fp32 normal equations);
-        # the stable functionals are the predictions and d_stat
-        np.testing.assert_allclose(np.asarray(ref.fitted),
-                                   np.asarray(sh.fitted), rtol=1e-2, atol=1e-3)
-        np.testing.assert_allclose(float(ref.d_stat), float(sh.d_stat),
-                                   rtol=1e-5)
-
-        # the pipeline's density/leverage agree with the core (host) path
-        p_core = core_kde.kde_direct(data.x, kde_sample, h)
-        sa = leverage.sa_leverage(p_core, lam, kern, d, n=n)
-        np.testing.assert_allclose(np.asarray(sh.probs), np.asarray(sa.probs),
-                                   rtol=2e-4, atol=1e-9)
-        print("PIPELINE_MATCH_OK")
+        assert np.all(np.isfinite(np.asarray(sh.state.fit.beta)))
+        print("SHARDED_LEVERAGE_OK")
     """)
-    assert "PIPELINE_MATCH_OK" in out
+    assert "SHARDED_LEVERAGE_OK" in out
 
 
 @pytest.mark.slow
@@ -113,17 +101,52 @@ def test_binned_kde_sharded_matches_oracle():
 
 
 @pytest.mark.slow
-def test_pipeline_lowers_on_production_like_mesh():
+def test_sharded_fit_gram_program_all_reduces_on_2d_mesh():
+    """SAKRRPipeline.fit on a (2, 2) (data, model) mesh runs, and the Gram
+    program it compiles all-reduces G (m, m) and rhs (m,) across the data
+    axis."""
     out = run_sub("""
-        from repro.core import distributed as D
+        import re
+        import numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import nystrom, streaming
+        from repro.data import krr_data
+        from repro.distributed import sharding as shd
         from repro.launch import mesh as mesh_lib
-        from repro.roofline import analysis as roofline
-        mesh = mesh_lib.make_local_mesh_2d(model_parallelism=4)
-        lowered, compiled = D.lower_pipeline(mesh, n=65536, d=3)
-        cost = roofline.cost_dict(compiled)
-        assert cost.get("flops", 0) > 0
-        txt = compiled.as_text()
-        assert "all-reduce" in txt  # the K_nm^T K_nm reduction
-        print("PIPELINE_LOWER_OK", int(cost["flops"]))
-    """)
-    assert "PIPELINE_LOWER_OK" in out
+        from repro.pipeline import PipelineConfig, SAKRRPipeline
+
+        calls = []
+        program = streaming._program
+
+        def recording(local, *a, **kw):
+            fn = program(local, *a, **kw)
+
+            def call(*args):
+                calls.append((local, fn, args))
+                return fn(*args)
+            return call
+
+        streaming._program = recording
+        m = 32
+        data = krr_data.bimodal(jax.random.PRNGKey(1), 4096, d=3)
+        cfg = PipelineConfig(num_landmarks=m, kde_grid_size=32, tile=512)
+        mesh = mesh_lib.make_local_mesh_2d(model_parallelism=2)
+        assert mesh.shape == {"data": 2, "model": 2}
+        x = jax.device_put(data.x, NamedSharding(mesh, P("data", None)))
+        y = jax.device_put(data.y, NamedSharding(mesh, P("data")))
+        with shd.activate(mesh):
+            pipe = SAKRRPipeline(cfg).fit(x, y)
+        assert np.all(np.isfinite(np.asarray(pipe.state.fit.beta)))
+
+        (fn, args), = [(fn, args) for local, fn, args in calls
+                       if getattr(local, "func", None)
+                       is nystrom._gram_local]
+        hlo = fn.lower(*args).compile().as_text()
+        reduced = " ".join(line.split("all-reduce(")[0]
+                           for line in hlo.splitlines()
+                           if "all-reduce(" in line)
+        assert re.search(rf"f32\\[{m},{m}\\]", reduced), reduced
+        assert re.search(rf"f32\\[{m}\\]", reduced), reduced
+        print("GRAM_ALL_REDUCE_OK")
+    """, devices=4)
+    assert "GRAM_ALL_REDUCE_OK" in out

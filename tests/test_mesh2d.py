@@ -97,11 +97,11 @@ def test_solve_multi_2d_mesh_bit_equal_to_1d():
         k_mm = kernel_matrix(kern, xm)
         lam_grid = [1e-3, 3e-3, 1e-2, 3e-2]
 
-        eager = nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid)
+        eager = nystrom.solve_normal_eq(g, rhs, k_mm, n, lam_grid)
         with sharding.activate(mesh_lib.make_local_mesh()):
-            ref = nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid)
+            ref = nystrom.solve_normal_eq(g, rhs, k_mm, n, lam_grid)
         with sharding.activate(mesh_lib.make_local_mesh_2d(2)):
-            shd = nystrom.solve_normal_eq_multi(g, rhs, k_mm, n, lam_grid)
+            shd = nystrom.solve_normal_eq(g, rhs, k_mm, n, lam_grid)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(shd))
         # the meshless eager loop differs only by jit-time FMA fusion
         np.testing.assert_allclose(np.asarray(eager), np.asarray(ref),

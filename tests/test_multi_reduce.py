@@ -19,6 +19,7 @@ import pytest
 from repro.core import kernels as K, nystrom, streaming
 from repro.core.kernels import kernel_matrix
 from repro.data import krr_data
+from repro.pipeline import stages
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KERN = K.Matern(nu=1.5)
@@ -222,7 +223,9 @@ def test_fit_streaming_scored_moments_match_direct():
     y = jnp.sin(x[:, 0]) + 0.1 * jax.random.normal(jax.random.PRNGKey(9),
                                                    (n,), jnp.float32)
     idx = jnp.arange(m)
-    fit, mom = nystrom.fit_streaming_scored(KERN, x, y, 1e-3, idx, tile=512)
+    state = nystrom.normal_eq_state(KERN, x, y[:, None], idx, tile=512)
+    fit = nystrom.solve_from_state(state, 1e-3)
+    mom = stages.score_moments(state, y, None)
     ref = nystrom.fit_streaming(KERN, x, y, 1e-3, idx, tile=512)
     # the scored fit's rhs is column 0 of a widened (n, 1+r) gemm — same
     # products as the (n,) gemv but a different XLA reduction order, so the

@@ -163,3 +163,60 @@ def test_sharded_stage_spans_carry_chips_and_psum_bytes():
                 "repro/kde/readback"):
         (_, _, s, e), = named(out["True"], sub)
         assert k0 <= s and e <= k1, sub
+
+
+GRID = textwrap.dedent("""
+    import json, sys
+    sys.path[:0] = {paths!r}
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import kernels as K, nystrom
+    from repro.data import krr_data
+    from repro.distributed import sharding as shd
+    from repro.launch import mesh as mesh_lib
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **_: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    data = krr_data.bimodal(jax.random.PRNGKey(0), 8192, d=3)
+    kern = K.Matern(nu=1.5)
+    idx = jax.numpy.arange(0, 8192, 128)
+    lams = [1e-2, 1e-3, 1e-4, 1e-5]
+    out = {{}}
+    for name, mesh in (
+            ("data", mesh_lib.make_local_mesh("data", jax.devices()[:4])),
+            ("data_model", mesh_lib.make_local_mesh_2d(2))):
+        x = jax.device_put(data.x, NamedSharding(mesh, P("data", None)))
+        y = jax.device_put(data.y, NamedSharding(mesh, P("data")))
+        with shd.activate(mesh):
+            state = nystrom.normal_eq_state(kern, x, y, idx, tile=1024)
+            nystrom.solve_from_state(state, lams).beta.block_until_ready()
+            before = len(compiles)
+            grid = nystrom.solve_from_state(state, lams)
+            grid.beta.block_until_ready()
+            out[name] = {{
+                "compiles_in_second_solve": len(compiles) - before,
+                "rows_equal_single": all(
+                    np.array_equal(np.asarray(grid.beta[i]), np.asarray(
+                        nystrom.solve_from_state(state, lam).beta))
+                    for i, lam in enumerate(lams))}}
+    print(json.dumps(out))
+""")
+
+
+def test_second_sharded_lam_grid_solve_compiles_nothing():
+    """A lam grid solved again from the same sharded state, under a data
+    mesh and a (data, model) mesh, compiles no program, and each row is
+    bitwise the single-lam solve."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = GRID.format(paths=[os.path.join(REPO, "src")])
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    for mesh in ("data", "data_model"):
+        assert out[mesh]["compiles_in_second_solve"] == 0, out
+        assert out[mesh]["rows_equal_single"], out

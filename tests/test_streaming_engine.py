@@ -173,11 +173,11 @@ def test_fit_streaming_multi_bit_parity():
     xm = jnp.take(data.x, idx, axis=0)
     g, rhs = _scan_normal_eq_ref(KERN, data.x, xm, data.y, tile=512)
     k_mm = kernel_matrix(KERN, xm).astype(g.dtype)
-    fits = nystrom.fit_streaming_multi(KERN, data.x, data.y, lams, idx,
-                                       tile=512)
-    for lam, fit in zip(lams, fits):
+    state = nystrom.normal_eq_state(KERN, data.x, data.y, idx, tile=512)
+    grid = nystrom.solve_from_state(state, lams)
+    for lam, beta in zip(lams, grid.beta):
         want = nystrom.solve_normal_eq(g, rhs, k_mm, 2048, lam)
-        np.testing.assert_array_equal(np.asarray(fit.beta), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(beta), np.asarray(want))
 
 
 @pytest.mark.parametrize("tile", [100, 333, 4096])
@@ -196,10 +196,10 @@ def test_predict_streaming_multi_bit_parity():
     (1, n) stacking."""
     data = krr_data.bimodal(jax.random.PRNGKey(3), 1024, d=3)
     idx = jnp.arange(0, 1024, 16)[:48]
-    fits = nystrom.fit_streaming_multi(KERN, data.x, data.y, [1e-3], idx,
-                                       tile=256)
-    multi = nystrom.predict_streaming_multi(KERN, fits, data.x[:300],
-                                            tile=128)
+    state = nystrom.normal_eq_state(KERN, data.x, data.y, idx, tile=256)
+    grid = nystrom.solve_from_state(state, [1e-3])
+    multi = nystrom.predict_streaming(KERN, grid._replace(beta=grid.beta.T),
+                                      data.x[:300], tile=128).T
     assert multi.shape == (1, 300)
 
 
